@@ -53,6 +53,29 @@ def exp2i(e: jax.Array) -> jax.Array:
     return jax.lax.bitcast_convert_type(bits, jnp.float32)
 
 
+#: Smallest 23-bit float32 fraction whose significand 1.f exceeds sqrt(2).
+_SQRT2_FRACTION = 0x3504F4
+
+
+def round_log2(mag: jax.Array) -> jax.Array:
+    """round(log2(mag)) for positive float32 ``mag``, exactly, from bits.
+
+    log2(2^e * 1.f) = e + log2(1.f) rounds up exactly when 1.f > sqrt(2),
+    so the result is the unbiased exponent plus one where the fraction is
+    at least ``_SQRT2_FRACTION`` (no float equals sqrt(2), so there are
+    no ties).  A transcendental ``log2`` is not correctly rounded and
+    differs between XLA and the TPU kernel compiler, which flipped PoT
+    codes next to the sqrt(2) boundaries; this form gives the same bits
+    on every backend.  A subnormal gives -127 or -126, below every PoT
+    grid's -emax, so it quantizes to zero as its exact exponent would.
+    Returns float32.
+    """
+    bits = jax.lax.bitcast_convert_type(mag.astype(jnp.float32), jnp.int32)
+    e = ((bits >> 23) & 0xFF) - 127
+    e = e + ((bits & 0x7FFFFF) >= _SQRT2_FRACTION).astype(jnp.int32)
+    return e.astype(jnp.float32)
+
+
 def compute_beta(f: jax.Array, bits: int, axes=None, *,
                  conservative: bool = False) -> jax.Array:
     """Layer-wise PoT scale exponent beta = round(log2(max|F| / 2^emax)).
@@ -74,16 +97,15 @@ def compute_beta(f: jax.Array, bits: int, axes=None, *,
     amax = jnp.max(jnp.abs(f), axis=axes, keepdims=axes is not None)
     amax = amax.astype(jnp.float32)
     safe = jnp.where(amax > 0, amax, 1.0)
-    rnd = jnp.ceil if conservative else jnp.round
-    beta = rnd(jnp.log2(safe)).astype(jnp.int32) - emax
+    e = jnp.ceil(jnp.log2(safe)) if conservative else round_log2(safe)
+    beta = e.astype(jnp.int32) - emax
     return jnp.where(amax > 0, beta, 0)
 
 
 def _log2_round_nearest(mag: jax.Array) -> jax.Array:
     """round(log2(mag)) with mag==0 mapped to a very negative exponent."""
     safe = jnp.where(mag > 0, mag, 1.0)
-    e = jnp.round(jnp.log2(safe))
-    return jnp.where(mag > 0, e, -(2.0 ** 20))
+    return jnp.where(mag > 0, round_log2(safe), -(2.0 ** 20))
 
 
 def _log2_round_stochastic(mag: jax.Array, key: jax.Array) -> jax.Array:

@@ -10,10 +10,12 @@
   :func:`grad_da_matmul` / :func:`grad_dw_matmul` expose the two halves
   individually (autotuner, benchmarks).
 
-On this CPU container the kernels run in interpret mode (the Pallas body
-executes in Python); on TPU set ``interpret=False`` (default resolves by
-backend).  Ragged shapes are zero-padded to block multiples — zero padding
-is exact for both the quantizer (0 -> 0) and the matmul.
+Off the TPU the kernels run in interpret mode (the Pallas body executes
+in Python); on the TPU they compile through Mosaic (``interpret=None``
+resolves by backend).  Ragged shapes are zero-padded to block multiples —
+zero padding is exact for both the quantizer (0 -> 0) and the matmul.
+Under an ambient multi-device mesh each kernel runs whole on every
+device (:func:`_whole`).
 
 Block shapes default to ``None`` = consult ``kernels/autotune.py`` (tuned
 cache -> heuristic); the fixed-order reduction makes every tiling
@@ -26,6 +28,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.core import potq
 from repro.kernels import autotune
@@ -36,6 +39,24 @@ from repro.kernels import potq_matmul as _k
 
 def _default_interpret() -> bool:
     return jax.default_backend() != "tpu"
+
+
+def _whole(kernel, **static):
+    """``kernel`` with its static arguments bound, run whole on every
+    device of the ambient abstract mesh (set by ``jax.set_mesh`` or
+    ``actshard.use_plan``) when it has several.
+
+    The SPMD partitioner cannot split a Mosaic kernel and refuses to
+    lower one under a multi-device jit.  Inside a ``shard_map`` whose
+    specs are all replicated each device receives the full operands (the
+    partitioner gathers sharded ones) and computes the full result, so
+    the output has the bits of a one-device run."""
+    fn = functools.partial(kernel, **static)
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.size == 1:
+        return fn
+    return jax.shard_map(fn, mesh=mesh, in_specs=P(), out_specs=P(),
+                         check_vma=False)
 
 
 def _pad_to(x: jax.Array, m0: int, m1: int) -> jax.Array:
@@ -83,7 +104,7 @@ def potq_matmul(
 
     def beta_of(amax, emax):
         safe = jnp.where(amax > 0, amax, 1.0)
-        b = jnp.round(jnp.log2(safe)).astype(jnp.int32) - emax
+        b = potq.round_log2(safe).astype(jnp.int32) - emax
         return jnp.where(amax > 0, b, 0)
 
     beta_a = beta_of(a_eff_max, emax_a)
@@ -99,22 +120,10 @@ def potq_matmul(
     )
     ap = _pad_to(_pad_to(a, 8, 128), bm_, bk_)
     wp = _pad_to(_pad_to(w, 128, 128), bk_, bn_)
-    out = _k.potq_matmul_padded(
-        ap,
-        wp,
-        sa,
-        sw,
-        deq,
-        one(w_mean),
-        one(clip_t),
-        emax_a=emax_a,
-        emax_w=emax_w,
-        quantize=True,
-        bm=bm_,
-        bn=bn_,
-        bk=bk_,
-        interpret=interpret,
-    )
+    out = _whole(
+        _k.potq_matmul_padded, emax_a=emax_a, emax_w=emax_w, quantize=True,
+        bm=bm_, bn=bn_, bk=bk_, interpret=interpret,
+    )(ap, wp, sa, sw, deq, one(w_mean), one(clip_t))
     return out[:m, :n]
 
 
@@ -138,20 +147,10 @@ def pot_value_matmul(
     bm_, bn_, bk_ = autotune.resolve(m, k, n, bm, bn, bk, quantize=False)
     xp = _pad_to(_pad_to(x, 8, 128), bm_, bk_)
     yp = _pad_to(_pad_to(y, 128, 128), bk_, bn_)
-    out = _k.potq_matmul_padded(
-        xp,
-        yp,
-        one(1.0),
-        one(1.0),
-        one(1.0),
-        one(0.0),
-        one(jnp.inf),
-        quantize=False,
-        bm=bm_,
-        bn=bn_,
-        bk=bk_,
+    out = _whole(
+        _k.potq_matmul_padded, quantize=False, bm=bm_, bn=bn_, bk=bk_,
         interpret=interpret,
-    )
+    )(xp, yp, one(1.0), one(1.0), one(1.0), one(0.0), one(jnp.inf))
     return out[:m, :n]
 
 
@@ -207,20 +206,18 @@ def grad_da_matmul(
         a = a.astype(jnp.float32)
         ap = _pad_to(a, bm_, bn_)
         assert ap.shape == (gp.shape[0], wp.shape[0])
-        out, rows = _kg.grad_da_padded(
-            gp, wp, ap, sg, deq, jnp.full((1, 1), clip_t, jnp.float32),
-            emax_g=emax_g, prc=True, bm=bm_, bn=bn_, bk=bk_,
-            interpret=interpret,
-        )
+        out, rows = _whole(
+            _kg.grad_da_padded, emax_g=emax_g, prc=True, bm=bm_, bn=bn_,
+            bk=bk_, interpret=interpret,
+        )(gp, wp, ap, sg, deq, jnp.full((1, 1), clip_t, jnp.float32))
         # every lane of a row carries the same partial; the final
         # tiling-independent reduction over the fixed-shape (M,) vector
         # belongs to the caller (potq_grad_matmuls / tests)
         return out[:m, :k], rows[:m, 0]
-    out = _kg.grad_da_padded(
-        gp, wp, gp, sg, deq, jnp.full((1, 1), jnp.inf, jnp.float32),
-        emax_g=emax_g, prc=False, bm=bm_, bn=bn_, bk=bk_,
-        interpret=interpret,
-    )
+    out = _whole(
+        _kg.grad_da_padded, emax_g=emax_g, prc=False, bm=bm_, bn=bn_,
+        bk=bk_, interpret=interpret,
+    )(gp, wp, gp, sg, deq, jnp.full((1, 1), jnp.inf, jnp.float32))
     return out[:m, :k], None
 
 
@@ -250,10 +247,10 @@ def grad_dw_matmul(
     )
     ap = _pad_to(_pad_to(aq, 128, 128), bk_, bm_)
     gp = _pad_to(_pad_to(g, 128, 128), bk_, bn_)
-    out = _kg.grad_dw_padded(
-        ap, gp, sg, deq, emax_g=emax_g, bm=bm_, bn=bn_, bk=bk_,
+    out = _whole(
+        _kg.grad_dw_padded, emax_g=emax_g, bm=bm_, bn=bn_, bk=bk_,
         interpret=interpret,
-    )
+    )(ap, gp, sg, deq)
     return out[:k, :nn]
 
 
@@ -326,7 +323,8 @@ def potq_encode(
     bm_ = min(bm, xp.shape[0])
     bn_ = min(bn, max(128, xp.shape[1]))
     xp = _pad_to(xp, bm_, bn_)
-    codes = _ke.potq_encode_padded(
-        xp, scale, emax=emax, bm=bm_, bn=bn_, interpret=interpret
-    )[:m, :n]
+    codes = _whole(
+        _ke.potq_encode_padded, emax=emax, bm=bm_, bn=bn_,
+        interpret=interpret,
+    )(xp, scale)[:m, :n]
     return codes.reshape(orig_shape), beta

@@ -17,6 +17,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.core import potq
+
 DEFAULT_BM = 256
 DEFAULT_BN = 512
 
@@ -25,7 +27,7 @@ def _encode_kernel(x_ref, scale_ref, o_ref, *, emax: int):
     x = x_ref[...].astype(jnp.float32) * scale_ref[0, 0]  # 2^-beta scaling
     mag = jnp.abs(x)
     safe = jnp.where(mag > 0, mag, 1.0)
-    e = jnp.round(jnp.log2(safe))
+    e = potq.round_log2(safe)
     under = (e < -emax) | (mag == 0)
     e = jnp.clip(e, float(-emax), float(emax))
     code = (e.astype(jnp.int32) + (emax + 1))  # magnitude code in [1, 2e+1]

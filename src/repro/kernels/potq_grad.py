@@ -49,7 +49,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.ref import CANONICAL_BK
+from repro.kernels.ref import CANONICAL_BK, chunk_rowsum
 # One accumulation-scheme tag governs forward AND backward kernels: all
 # reduce in canonical CANONICAL_BK chunks, left fold.  Any change to the
 # backward reduction order or epilogue math must bump it in
@@ -121,10 +121,8 @@ def _grad_da_kernel(
             # canonical 128-wide K chunks of the row reduction, ascending
             # global chunk order (j ascends for fixed i), left fold
             for c in range(bn // CANONICAL_BK):
-                s = jnp.sum(
-                    contrib[:, c * CANONICAL_BK:(c + 1) * CANONICAL_BK],
-                    axis=1,
-                )
+                s = chunk_rowsum(
+                    contrib[:, c * CANONICAL_BK:(c + 1) * CANONICAL_BK])
                 dgrows_ref[...] += s[:, None]
             da_ref[...] = jnp.where(clipped, 0.0, da_raw)
         else:

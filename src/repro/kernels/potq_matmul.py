@@ -35,6 +35,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core import potq
+
 DEFAULT_BM = 256
 DEFAULT_BN = 256
 DEFAULT_BK = 256
@@ -49,14 +51,14 @@ from repro.kernels.ref import CANONICAL_BK  # noqa: E402
 # the in-kernel quantizer math — the autotune cache (kernels/autotune.py)
 # keys on it, so stale tuned entries (and any golden outputs derived from
 # the old order) are invalidated automatically.
-ACC_SCHEME = "canonical-k128-leftfold-v1"
+ACC_SCHEME = "canonical-k128-leftfold-v2"
 
 
 def _quantize_tile(x, emax: int):
     """Round-to-nearest PoT quantization of a pre-scaled VMEM tile."""
     mag = jnp.abs(x)
     safe = jnp.where(mag > 0, mag, 1.0)
-    e = jnp.round(jnp.log2(safe))
+    e = potq.round_log2(safe)
     under = (e < -emax) | (mag == 0)
     e = jnp.clip(e, float(-emax), float(emax))
     # exact 2^e via exponent-bit construction (jnp.exp2 is inexact on

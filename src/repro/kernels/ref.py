@@ -31,7 +31,7 @@ def quantize_tile_ref(x: jax.Array, emax: int) -> jax.Array:
     """
     mag = jnp.abs(x)
     safe = jnp.where(mag > 0, mag, 1.0)
-    e = jnp.round(jnp.log2(safe))
+    e = potq.round_log2(safe)
     under = (e < -emax) | (mag == 0)
     e = jnp.clip(e, -emax, emax)
     q = jnp.where(under, 0.0, exp2i(jnp.where(under, 0.0, e)))
@@ -65,13 +65,28 @@ def pot_value_matmul_ref(x: jax.Array, y: jax.Array) -> jax.Array:
     return out
 
 
+def chunk_rowsum(x: jax.Array) -> jax.Array:
+    """Row sums of a ``(rows, CANONICAL_BK)`` chunk by a halving tree of
+    elementwise adds: ``x[:, :64] + x[:, 64:]``, then 32, ..., 1.
+
+    The order is written out because a backend's own lane reduction
+    (``sum(axis=1)``) may associate differently in XLA and in the TPU
+    kernel compiler; elementwise f32 adds give the same bits on both.
+    """
+    w = x.shape[1]
+    while w > 1:
+        w //= 2
+        x = x[:, :w] + x[:, w:2 * w]
+    return x[:, 0]
+
+
 def grad_rowsum_ref(x: jax.Array) -> jax.Array:
     """Canonical fixed-order row reduction: sum over the last axis in
     ``CANONICAL_BK``-wide chunks, left-folded in ascending chunk order.
 
     This is the numeric spec for the dgamma epilogue of the fused backward
-    kernel: each 128-wide chunk is reduced with one fixed-shape
-    ``sum(axis=1)`` (identical bits for any row-tile height) and the chunk
+    kernel: each 128-wide chunk is reduced by :func:`chunk_rowsum`
+    (identical bits for any row-tile height and backend) and the chunk
     partials fold left in global chunk order — so the (M,) result is
     independent of the kernel's (bm, bn, bk) tiling.  Zero padding appends
     exact-zero partials.
@@ -82,7 +97,7 @@ def grad_rowsum_ref(x: jax.Array) -> jax.Array:
         x = jnp.pad(x, ((0, 0), (0, pad)))
     out = jnp.zeros((x.shape[0],), jnp.float32)
     for c in range(0, k + pad, CANONICAL_BK):
-        out = out + jnp.sum(x[:, c:c + CANONICAL_BK], axis=1)
+        out = out + chunk_rowsum(x[:, c:c + CANONICAL_BK])
     return out
 
 

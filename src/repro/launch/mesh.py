@@ -1,7 +1,7 @@
 """Production mesh construction (thin re-export).
 
-The real implementation lives in :mod:`repro.parallel.meshes`, the
-version-portable mesh compat shim; this module keeps the historical
+The real implementation lives in :mod:`repro.parallel.meshes`; this
+module keeps the historical
 ``repro.launch.mesh`` import path working for launchers and scripts.
 """
 from __future__ import annotations

@@ -678,8 +678,8 @@ def chunk_step(cfg, policy, params, tokens, n_new, cache):
                 v.astype(cv_self.dtype), mode="drop"
             )
         # scatter-then-attend over the post-scatter span view — the same
-        # reduction decode_step performs (decode fast-path bit-equality);
-        # encdec is never windowed, so no ring wrap can occur
+        # reduction decode_step performs; encdec is never windowed, so no
+        # ring wrap can occur
         if kvq:
             kv_k = _kv_page_view(nk, nkb, table, span, spec, q.dtype)
             kv_v = _kv_page_view(nv, nvb, table, span, spec, q.dtype)

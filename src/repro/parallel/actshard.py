@@ -38,12 +38,20 @@ def active_plan() -> Optional[ShardingPlan]:
 
 @contextlib.contextmanager
 def use_plan(plan: Optional[ShardingPlan]):
-    """Activate ``plan`` for in-model activation pinning (None deactivates)."""
+    """Activate ``plan`` for in-model activation pinning (None deactivates).
+
+    The plan's mesh is also made the ambient abstract mesh, under which
+    each Pallas kernel runs whole on every device (``kernels/ops.py``);
+    eager arrays stay where they are."""
     global _ACTIVE
     prev = _ACTIVE
     _ACTIVE = plan
     try:
-        yield
+        if plan is None:
+            yield
+        else:
+            with jax.sharding.use_abstract_mesh(plan.mesh.abstract_mesh):
+                yield
     finally:
         _ACTIVE = prev
 

@@ -1,32 +1,11 @@
-"""Version-portable mesh construction (compat shim).
+"""Mesh construction: the one place that builds ``Mesh``/``AbstractMesh``.
 
-JAX's mesh-building APIs have moved several times; this module is the one
-place in the codebase allowed to know about that.  Everything else asks
-for a mesh by ``(axis_sizes, axis_names)`` and gets whatever the installed
-JAX can build.
-
-Compatibility matrix (feature-detected at runtime — no version pins):
-
-==================  ==================================  =========================
-construct           old API (jax <= 0.4.x)              new API (jax >= 0.5)
-==================  ==================================  =========================
-``AbstractMesh``    ``AbstractMesh(((name, size),       ``AbstractMesh(
-                    ...))`` — one positional            (size, ...), (name, ...))``
-                    tuple-of-pairs ``shape_tuple``      — sizes and names split,
-                                                        kw-only ``axis_types``
-``Mesh`` (devices)  ``Mesh(device_ndarray,              same, plus
-                    axis_names)``;                      ``jax.make_mesh`` with
-                    ``jax.make_mesh`` from 0.4.35       explicit-sharding
-                                                        ``axis_types``
-introspection       ``mesh.shape`` (OrderedDict),       same attributes kept;
-                    ``mesh.axis_names``,                ``shape_tuple`` on
-                    ``mesh.axis_sizes``                 abstract meshes only
-==================  ==================================  =========================
-
-Detection is by *trial construction + read-back verification* (the built
-mesh must report the requested names and sizes), not by signature
-inspection, so intermediate releases that accept both call styles still
-resolve to a correct mesh.
+Everything else asks for a mesh by ``(axis_sizes, axis_names)``. Every
+mesh built here has ``AxisType.Auto`` axes: the planner and
+``repro.parallel.actshard`` place arrays with ``NamedSharding`` pins and
+leave the rest to the SPMD partitioner, which is what Auto axes mean.
+(``jax.make_mesh`` alone would give Explicit axes, under which ops such
+as the embedding gather must name their output sharding.)
 
 Production topologies live here too: single-pod 16x16 = 256 chips
 (``('data', 'model')``) and multi-pod 2x16x16 = 512 chips
@@ -39,7 +18,7 @@ import math
 from typing import Optional, Sequence, Tuple
 
 import jax
-from jax.sharding import AbstractMesh, Mesh
+from jax.sharding import AbstractMesh, AxisType, Mesh
 
 SINGLE_POD = ((16, 16), ("data", "model"))
 MULTI_POD = ((2, 16, 16), ("pod", "data", "model"))
@@ -52,10 +31,7 @@ def axis_names(mesh) -> Tuple[str, ...]:
 
 def axis_sizes(mesh) -> Tuple[int, ...]:
     """Axis sizes of a concrete or abstract mesh, in axis order."""
-    sizes = getattr(mesh, "axis_sizes", None)
-    if sizes is not None:
-        return tuple(int(s) for s in sizes)
-    return tuple(int(mesh.shape[a]) for a in mesh.axis_names)
+    return tuple(int(s) for s in mesh.axis_sizes)
 
 
 def shape_dict(mesh) -> dict:
@@ -63,42 +39,18 @@ def shape_dict(mesh) -> dict:
     return dict(zip(axis_names(mesh), axis_sizes(mesh)))
 
 
-def _mesh_matches(mesh, sizes: Tuple[int, ...], names: Tuple[str, ...]) -> bool:
-    try:
-        return axis_names(mesh) == names and axis_sizes(mesh) == sizes
-    except Exception:
-        return False
-
-
-def make_abstract_mesh(sizes: Sequence[int], names: Sequence[str]) -> AbstractMesh:
-    """Build an ``AbstractMesh`` under whichever signature this JAX has.
-
-    Tries the new split ``(axis_sizes, axis_names)`` call first, then the
-    old tuple-of-pairs ``shape_tuple`` call; each candidate is verified by
-    reading the names/sizes back, so a constructor that "succeeds" by
-    misinterpreting its arguments is rejected.
-    """
+def _axes(sizes: Sequence[int], names: Sequence[str]):
     sizes = tuple(int(s) for s in sizes)
     names = tuple(str(n) for n in names)
     if len(sizes) != len(names):
         raise ValueError(f"axis count mismatch: sizes={sizes} names={names}")
-    candidates = (
-        lambda: AbstractMesh(sizes, names),          # new: sizes, names
-        lambda: AbstractMesh(tuple(zip(names, sizes))),  # old: ((name, size), ...)
-    )
-    errors = []
-    for build in candidates:
-        try:
-            mesh = build()
-        except (TypeError, ValueError) as e:
-            errors.append(e)
-            continue
-        if _mesh_matches(mesh, sizes, names):
-            return mesh
-    raise RuntimeError(
-        f"no AbstractMesh signature accepted sizes={sizes} names={names} "
-        f"on jax {jax.__version__}: {errors}"
-    )
+    return sizes, names, (AxisType.Auto,) * len(names)
+
+
+def make_abstract_mesh(sizes: Sequence[int], names: Sequence[str]) -> AbstractMesh:
+    """An ``AbstractMesh`` (no devices needed) with Auto axes."""
+    sizes, names, types = _axes(sizes, names)
+    return AbstractMesh(sizes, names, axis_types=types)
 
 
 def make_mesh(
@@ -107,24 +59,15 @@ def make_mesh(
     *,
     devices: Optional[Sequence] = None,
 ) -> Mesh:
-    """Build a concrete device mesh across JAX variants.
-
-    Prefers ``jax.make_mesh`` (which picks a bandwidth-aware device
-    order) when present and no explicit device list is given; otherwise
-    falls back to reshaping ``devices`` (default: ``jax.devices()``)
-    into ``Mesh(device_array, names)``.
-    """
-    sizes = tuple(int(s) for s in sizes)
-    names = tuple(str(n) for n in names)
-    if devices is None and hasattr(jax, "make_mesh"):
-        return jax.make_mesh(sizes, names)
-    import numpy as np
-
+    """A concrete device mesh with Auto axes over the first
+    ``prod(sizes)`` of ``devices`` (default: ``jax.devices()``), in the
+    bandwidth-aware order ``jax.make_mesh`` picks."""
+    sizes, names, types = _axes(sizes, names)
     n = math.prod(sizes)
     devs = list(devices) if devices is not None else jax.devices()
     if len(devs) < n:
         raise ValueError(f"need {n} devices for mesh {sizes}, have {len(devs)}")
-    return Mesh(np.asarray(devs[:n]).reshape(sizes), names)
+    return jax.make_mesh(sizes, names, axis_types=types, devices=devs[:n])
 
 
 def make_production_mesh(*, multi_pod: bool = False, abstract: bool = False):
@@ -141,7 +84,8 @@ def make_production_mesh(*, multi_pod: bool = False, abstract: bool = False):
 
 
 def make_host_mesh() -> Mesh:
-    """Degenerate 1-host mesh for CPU tests (all rules -> replicate)."""
+    """``(data=1, model=n)`` mesh over this process's ``n`` devices; on
+    one device every rule degrades to replicate."""
     n = len(jax.devices())
     return make_mesh((1, n), ("data", "model"))
 
@@ -153,9 +97,8 @@ def make_serving_mesh(*, data: Optional[int] = None, model: int = 1) -> Mesh:
     claimed by ``model`` — on a 1-device CPU it degrades to ``(1, 1)``
     (all rules -> replicate), while under the multi-process smoke path
     (``repro.parallel.smoke``, run with
-    ``XLA_FLAGS=--xla_force_host_platform_device_count=N``) the same call
-    yields a real N-way data axis, so the identical engine code exercises
-    genuinely sharded slots on stock CPU runners."""
+    ``XLA_FLAGS=--xla_force_host_platform_device_count=N``) or on an
+    N-chip host the same call yields a real N-way data axis."""
     n = len(jax.devices())
     if model < 1 or n % model:
         raise ValueError(f"model={model} must divide the {n} visible devices")

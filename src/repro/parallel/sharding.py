@@ -14,7 +14,7 @@ Baseline production scheme (applies uniformly to every arch):
 Rules are *functions of the mesh*, so the same model code runs on the
 single-pod (16,16) and multi-pod (2,16,16) meshes, and on 1-device CPU
 test meshes (where every rule degrades to replication).  Meshes may be
-concrete or abstract — introspection goes through the compat shim in
+concrete or abstract — introspection goes through
 ``repro.parallel.meshes``.
 
 These are the low-level rules; consumers should go through the validated

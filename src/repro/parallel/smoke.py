@@ -3,8 +3,8 @@
 CI has no accelerator fleet, so the sharded pool's collective paths would
 go untested between here and a real pod.  XLA's host platform can fake a
 fleet: launched with ``XLA_FLAGS=--xla_force_host_platform_device_count=N``
-this driver sees N CPU devices, builds the ``(N, 1)`` serving mesh through
-the compat shim (``meshes.make_serving_mesh``), plans a sharded pool on it
+this driver sees N CPU devices, builds the ``(N, 1)`` serving mesh
+(``meshes.make_serving_mesh``), plans a sharded pool on it
 (``planner.plan_for(..., pool_slots=...)`` — slots, page tables, page
 stores over the real N-way data axis) and drives a deterministic request
 trace through the same :class:`repro.serve.PoolEngine` production code,

@@ -267,6 +267,9 @@ def _shared_step(kind: str, cfg, policy, body):
             )
         return _jitted(*args)
 
+    # lower/compile the very program the engine dispatches (tpu_custom_call
+    # counts, memory analysis, compile time)
+    checked.lower = jitted.lower
     return checked
 
 
@@ -682,7 +685,8 @@ class PoolEngine:
         self.spec = spec
         self.span = registry.pool_span(cfg, max_len)
         self.plan = plan
-        self._decode = make_decode_step(cfg, policy, plan=plan)
+        self._decode = (make_decode_step(cfg, policy, plan=plan)
+                        if prefill_chunk is None else None)
         self._chunk_step = (
             make_chunk_step(cfg, policy, plan=plan)
             if prefill_chunk is not None else None
@@ -1189,14 +1193,13 @@ class PoolEngine:
                     # self-draft pollution was restored above), so fall
                     # through to the plain fixed-shape dispatch
                 finishing = []
-                if chunk is None or (not prefilling and self.cfg.window is None):
-                    # decode fast-path: with nobody PREFILLING the fused
-                    # chunk step degenerates to plain decode — and the two
-                    # step bodies are bit-equal on decode rows (pinned by
-                    # tests/conformance), so dispatching the cheaper one
-                    # mid-request never changes served tokens.  Windowed
-                    # archs keep the chunk step (its in-chunk ring-wrap
-                    # concat layout differs from decode's scatter).
+                if chunk is None:
+                    # A chunked engine dispatches the chunk step even when
+                    # nobody is PREFILLING: on a TPU its decode rows are
+                    # not bit-equal to plain decode_step's (the shapes
+                    # differ, and so does XLA's reduction order), so
+                    # switching bodies by the neighbours' state would let
+                    # batching change a request's tokens.
                     ntok, _, cache = self._decode(
                         self.params, jnp.asarray(last_tok), cache
                     )

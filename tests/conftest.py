@@ -78,3 +78,19 @@ def _isolated_autotune_cache(tmp_path, monkeypatch):
     mod = sys.modules.get("repro.kernels.autotune")
     if mod is not None:
         mod.reset_cache(None)
+
+
+@pytest.fixture(autouse=True)
+def _restore_compile_cache_dir():
+    """Entry points (launch/train.py main) point JAX's persistent
+    compilation cache at the checkout; put back what the test found so
+    later tests in the worker do not write there."""
+    import jax
+
+    before = jax.config.jax_compilation_cache_dir
+    yield
+    if jax.config.jax_compilation_cache_dir != before:
+        from jax.experimental.compilation_cache import compilation_cache
+
+        jax.config.update("jax_compilation_cache_dir", before)
+        compilation_cache.reset_cache()

@@ -1,8 +1,8 @@
 """Planner subsystem tests: ShardingPlan validity on all meshes, plan-level
-validation failures, and the version-portable AbstractMesh compat shim."""
+validation failures, and mesh construction (Auto axis types)."""
 import jax
 import pytest
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 from repro import configs as C
 from repro.parallel import meshes, planner
@@ -252,23 +252,13 @@ def test_split_micro_consumes_active_plan(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# Mesh compat shim regression: pin behavior under BOTH AbstractMesh call
-# signatures, independent of which one the installed JAX uses.
+# Mesh construction: the split (sizes, names) AbstractMesh call and Auto
+# axis types on every mesh the module builds.
 # ---------------------------------------------------------------------------
 
 
-class _PairStyleMesh:
-    """Old API: AbstractMesh(((name, size), ...))."""
-
-    def __init__(self, shape_tuple, axis_types=None):
-        names, sizes = zip(*shape_tuple)  # TypeError on a tuple of ints
-        self.axis_names = tuple(names)
-        self.axis_sizes = tuple(int(s) for s in sizes)
-        self.shape = dict(zip(self.axis_names, self.axis_sizes))
-
-
 class _SplitStyleMesh:
-    """New API: AbstractMesh((size, ...), (name, ...))."""
+    """AbstractMesh((size, ...), (name, ...), axis_types=...)."""
 
     def __init__(self, axis_sizes, axis_names=None, axis_types=None):
         if axis_names is None or not all(
@@ -277,18 +267,32 @@ class _SplitStyleMesh:
             raise TypeError("expected (sizes, names)")
         self.axis_names = tuple(axis_names)
         self.axis_sizes = tuple(axis_sizes)
+        self.axis_types = axis_types
         self.shape = dict(zip(self.axis_names, self.axis_sizes))
 
 
-@pytest.mark.parametrize(
-    "fake", [_PairStyleMesh, _SplitStyleMesh], ids=["pair_style", "split_style"]
-)
+@pytest.mark.parametrize("fake", [_SplitStyleMesh], ids=["split_style"])
 def test_shim_resolves_either_abstract_mesh_signature(monkeypatch, fake):
     monkeypatch.setattr(meshes, "AbstractMesh", fake)
     m = meshes.make_abstract_mesh((2, 16, 16), ("pod", "data", "model"))
     assert meshes.axis_names(m) == ("pod", "data", "model")
     assert meshes.axis_sizes(m) == (2, 16, 16)
     assert meshes.shape_dict(m) == {"pod": 2, "data": 16, "model": 16}
+    assert m.axis_types == (AxisType.Auto,) * 3
+
+
+@pytest.mark.parametrize("build", ["concrete", "host", "serving", "abstract"])
+def test_make_mesh_axis_types_are_auto(build):
+    """Auto axes leave placement to NamedSharding pins + the partitioner;
+    an Explicit mesh (jax.make_mesh's own default) makes the embedding
+    gather demand an out_sharding and fails the train step."""
+    m = {
+        "concrete": lambda: meshes.make_mesh((1, 1), ("data", "model")),
+        "host": meshes.make_host_mesh,
+        "serving": meshes.make_serving_mesh,
+        "abstract": lambda: meshes.make_abstract_mesh((2, 2), ("data", "model")),
+    }[build]()
+    assert tuple(m.axis_types) == (AxisType.Auto,) * len(m.axis_names)
 
 
 def test_shim_builds_real_abstract_mesh_on_installed_jax():
