@@ -644,8 +644,8 @@ def chunk_step(cfg, policy, params, tokens, n_new, cache):
     view — the *same* reduction ``decode_step`` performs — and pad
     positions are zeroed at each norm output so per-row activation-scale
     groups match decode's.  Together these make a decode row (n_new == 1)
-    bit-equal between ``chunk_step`` and ``decode_step`` on the CPU (not
-    on a TPU, where the engine therefore never switches bodies).
+    bit-equal between ``chunk_step`` and ``decode_step``, which is what
+    lets the engine's decode fast-path switch step bodies mid-request.
 
     Returns (logits (B, V) at each slot's last valid position, new pooled
     cache).  Slot-pooled caches only (``len`` (B,), ``pos`` (B, span) —
@@ -749,8 +749,8 @@ def chunk_step(cfg, policy, params, tokens, n_new, cache):
             )
         else:
             # scatter-then-attend over the post-scatter span view — the
-            # same reduction decode_step performs; no window => no ring
-            # wrap => safe
+            # identical reduction decode_step performs (decode fast-path
+            # bit-equality); no window => no ring wrap => safe
             if kvq:
                 kv_k = _kv_page_view(nk, nkb, table, span, spec, q.dtype)
                 kv_v = _kv_page_view(nv, nvb, table, span, spec, q.dtype)

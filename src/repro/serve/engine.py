@@ -267,9 +267,6 @@ def _shared_step(kind: str, cfg, policy, body):
             )
         return _jitted(*args)
 
-    # lower/compile the very program the engine dispatches (tpu_custom_call
-    # counts, memory analysis, compile time)
-    checked.lower = jitted.lower
     return checked
 
 
@@ -685,8 +682,19 @@ class PoolEngine:
         self.spec = spec
         self.span = registry.pool_span(cfg, max_len)
         self.plan = plan
-        self._decode = (make_decode_step(cfg, policy, plan=plan)
-                        if prefill_chunk is None else None)
+        # Decode fast path: with nobody PREFILLING, a chunked engine may
+        # dispatch the cheaper plain decode step instead of the chunk step.
+        # That never changes served tokens only where the two step bodies
+        # are bit-equal on decode rows, which is pinned on the CPU
+        # (tests/conformance).  On a TPU v5e they are not: XLA runs the
+        # attention dots of one query row on the vector unit and those of
+        # a chunk on the MXU, and pool output stopped matching solo
+        # output (PERF.md).
+        # Windowed archs keep the chunk step everywhere (its in-chunk
+        # ring-wrap concat layout differs from decode's scatter).
+        self._fast_decode = (cfg.window is None
+                             and jax.default_backend() == "cpu")
+        self._decode = make_decode_step(cfg, policy, plan=plan)
         self._chunk_step = (
             make_chunk_step(cfg, policy, plan=plan)
             if prefill_chunk is not None else None
@@ -1193,13 +1201,7 @@ class PoolEngine:
                     # self-draft pollution was restored above), so fall
                     # through to the plain fixed-shape dispatch
                 finishing = []
-                if chunk is None:
-                    # A chunked engine dispatches the chunk step even when
-                    # nobody is PREFILLING: on a TPU its decode rows are
-                    # not bit-equal to plain decode_step's (the shapes
-                    # differ, and so does XLA's reduction order), so
-                    # switching bodies by the neighbours' state would let
-                    # batching change a request's tokens.
+                if chunk is None or (self._fast_decode and not prefilling):
                     ntok, _, cache = self._decode(
                         self.params, jnp.asarray(last_tok), cache
                     )

@@ -22,9 +22,11 @@ Chunked prefill (``PoolEngine(prefill_chunk=C)``) changes the
 computation *recipe* — activation-scale groups cover a chunk, not the
 whole prompt — so its reference is the same recipe driven solo: raw
 ``registry.chunk_step`` calls at batch 1 (per-tensor scales,
-quantize-at-use weights) for the prompt and every decode step, as the
-engine runs it.  The invariant under test is unchanged: batching never
-changes a request's tokens.
+quantize-at-use weights), mirroring the engine's chunking of the prompt
+— then, for window-free archs, plain ``registry.decode_step`` calls,
+mirroring the engine's decode fast-path (the two step bodies are
+bit-equal on decode rows; pinned below per backend).  The invariant
+under test is unchanged: batching never changes a request's tokens.
 
 Since PR 6 the pool cache is block-table **paged** (serve/slots.py), so
 the matrix gains a page-size axis: page = span (the legacy-equivalent
@@ -282,6 +284,18 @@ def _chunk_fn(cfg, policy):
     return _CHUNK_FNS[key]
 
 
+_DEC_FNS = {}
+
+
+def _dec_fn(cfg, policy):
+    key = (cfg, policy)
+    if key not in _DEC_FNS:
+        _DEC_FNS[key] = jax.jit(
+            lambda p, t, c: registry.decode_step(cfg, policy, p, t, c)
+        )
+    return _DEC_FNS[key]
+
+
 def _solo_chunked_reference(cfg, policy, params, req, chunk=CHUNK):
     """Batch-1 chunked loop: raw ``registry.chunk_step`` calls on a
     one-slot pool cache with quantize-at-use weights and per-tensor
@@ -289,9 +303,12 @@ def _solo_chunked_reference(cfg, policy, params, req, chunk=CHUNK):
     (the engine instead runs prequantized weights + per-sample scales
     inside a shared pool, so a match certifies the same three properties).
 
-    After the prompt it decodes through chunk-shaped steps too, exactly
-    like the engine, which never switches a chunked pool to plain
-    ``registry.decode_step``.
+    After the prompt, window-free archs switch to plain
+    ``registry.decode_step`` — mirroring the engine's decode fast-path
+    (with nobody PREFILLING it dispatches plain decode; the two step
+    bodies are bit-equal on decode rows, pinned by
+    ``test_decode_fast_path_matches_chunk_step``).  Windowed archs stay
+    on chunk-shaped decode, exactly like the engine.
     """
     step = _chunk_fn(cfg, policy)
     cache = registry.init_pool_cache(cfg, 1, MAX_LEN)
@@ -315,10 +332,16 @@ def _solo_chunked_reference(cfg, policy, params, req, chunk=CHUNK):
     tok = int(jnp.argmax(logits, -1)[0])
     out = [tok]
     one = jnp.asarray([1], jnp.int32)
+    dec_step = _dec_fn(cfg, policy) if cfg.window is None else None
     for _ in range(req.max_new_tokens - 1):
-        dec = np.zeros((1, chunk), np.int32)
-        dec[0, 0] = tok
-        logits, cache = step(params, jnp.asarray(dec), one, cache)
+        if dec_step is not None:  # engine decode fast-path
+            logits, cache = dec_step(
+                params, jnp.asarray([tok], jnp.int32), cache
+            )
+        else:
+            dec = np.zeros((1, chunk), np.int32)
+            dec[0, 0] = tok
+            logits, cache = step(params, jnp.asarray(dec), one, cache)
         tok = int(jnp.argmax(logits, -1)[0])
         out.append(tok)
     return np.asarray(out, np.int32)
@@ -329,7 +352,7 @@ _CHUNK_CACHE = {}
 
 
 def _run_chunked(arch, schedule, *, use_pallas=False, n=4, slots=2,
-                 chunk=CHUNK, page=None):
+                 chunk=CHUNK, page=None, backend=None):
     key = (arch, use_pallas, n, chunk)
     if key not in _CHUNK_CACHE:
         cfg, params = _params_for(arch)
@@ -341,13 +364,16 @@ def _run_chunked(arch, schedule, *, use_pallas=False, n=4, slots=2,
         }
         _CHUNK_CACHE[key] = (cfg, policy, params, reqs, solo, {})
     cfg, policy, params, reqs, solo, engines = _CHUNK_CACHE[key]
-    ekey = (slots, page)
+    ekey = (slots, page, backend)
     if ekey not in engines:
-        engines[ekey] = PoolEngine(
-            cfg, policy, params, max_slots=slots, max_len=MAX_LEN,
-            prefill_chunk=chunk,
-            **({"page_size": page} if page is not None else {}),
-        )
+        with pytest.MonkeyPatch.context() as mp:
+            if backend is not None:  # the engine picks its decode path by it
+                mp.setattr(jax, "default_backend", lambda: backend)
+            engines[ekey] = PoolEngine(
+                cfg, policy, params, max_slots=slots, max_len=MAX_LEN,
+                prefill_chunk=chunk,
+                **({"page_size": page} if page is not None else {}),
+            )
     arrivals = SCHEDULES[schedule](len(reqs))
     scheduled = [
         dataclasses.replace(r, arrival=a) for r, a in zip(reqs, arrivals)
@@ -370,6 +396,16 @@ def test_chunked_prefill_bit_identical(schedule, page):
     C tokens per pooled dispatch; every request's tokens bit-equal the
     same chunked recipe run alone — at every page geometry."""
     _run_chunked("llama3-8b", schedule, page=page)
+
+
+@pytest.mark.parametrize("schedule", ["staggered", "burst_then_tail"])
+def test_chunked_prefill_bit_identical_off_cpu(schedule):
+    """Off the CPU a chunked engine takes no decode fast path: every
+    pooled step is the chunk step, also when nobody is PREFILLING.  The
+    tokens still equal the solo chunked recipe (on the CPU the two step
+    bodies are bit-equal, so one reference covers both paths)."""
+    eng = _run_chunked("llama3-8b", schedule, page=4, backend="tpu")
+    assert not eng._fast_decode
 
 
 @pytest.mark.parametrize("page", PAGES)
@@ -470,12 +506,12 @@ def test_chunked_prefill_single_chunk_covers_prompt():
 
 @pytest.mark.parametrize("use_pallas", [False, True], ids=["jnp", "pallas"])
 def test_decode_fast_path_matches_chunk_step(use_pallas):
-    """The fused chunk step at ``n_new=1`` and the plain decode step are
-    **bit-equal on decode rows** on the CPU — same scatter-then-attend
-    reduction, pad rows zeroed before every activation-scale group —
-    pinned per backend: identical logits AND an identical cache
-    afterwards.  (The engine no longer relies on it: on a TPU the two
-    shapes reduce in different orders.)"""
+    """The engine's decode fast-path dispatches plain ``decode_step``
+    whenever no slot is PREFILLING.  That is only sound because the fused
+    chunk step at ``n_new=1`` and the plain decode step are **bit-equal
+    on decode rows** — same scatter-then-attend reduction, pad rows
+    zeroed before every activation-scale group — which this test pins
+    per backend: identical logits AND an identical cache afterwards."""
     arch = "llama3-8b"
     cfg, params = _params_for(arch)
     policy = PALLAS if use_pallas else PAPER_FAITHFUL

@@ -20,8 +20,8 @@ Matrix: {llama3, whisper} x {jnp, pallas} x >=2 arrival schedules, all
 on the 1-device serving mesh (rules degrade to replication but the full
 plan-carrying jit path — in/out shardings, donated sharded cache,
 ambient-plan contract — is exercised end to end), plus the carry-over
-pin that the decode and chunk step bodies stay bit-equal on the CPU *in
-the sharded path*, and a ``multiprocess`` smoke that reruns the engine over
+pin that the decode fast-path's two step bodies stay bit-equal *in the
+sharded path*, and a ``multiprocess`` smoke that reruns the engine over
 a real 2-way data axis via ``XLA_FLAGS=--xla_force_host_platform_
 device_count`` (repro.parallel.smoke).
 """
@@ -136,10 +136,13 @@ def test_sharded_pool_matches_single_device_pool(arch, use_pallas, schedule):
 
 @pytest.mark.parametrize("use_pallas", [False, True], ids=["jnp", "pallas"])
 def test_decode_fast_path_matches_chunk_step_sharded(use_pallas):
-    """The plan-jitted fused chunk step at ``n_new=1`` and the
-    plan-jitted decode step stay bit-equal on decode rows on the CPU —
+    """Carry-over pin: with no slot PREFILLING (and window=None) the
+    engine dispatches plain ``decode_step`` in the sharded path too.
+    Sound only because the plan-jitted fused chunk step at ``n_new=1``
+    and the plan-jitted decode step stay bit-equal on decode rows —
     identical tokens AND an identical sharded cache afterwards, pinned
-    per backend through the ``make_*_step(plan=)`` factories."""
+    here per backend through the same ``make_*_step(plan=)`` factories
+    the engine uses."""
     cfg, params = _params_for("llama3-8b")
     policy = PALLAS if use_pallas else PAPER_FAITHFUL
     mesh = meshes.make_serving_mesh()

@@ -1,4 +1,5 @@
 """End-to-end behaviour tests: CLI train driver (with restart), serving."""
+import importlib.util
 import math
 
 import jax
@@ -64,6 +65,17 @@ def test_compile_cache_dir(env_dir, monkeypatch, tmp_path):
         monkeypatch.setenv(compile_cache.ENV, str(want))
     assert compile_cache.enable() == str(want)
     assert jax.config.jax_compilation_cache_dir == str(want)
+
+
+def test_chip_smoke_refuses_a_cpu_backend(capsys):
+    """chip_smoke.py runs no phase and prints no result line off the TPU."""
+    path = compile_cache.DEFAULT_DIR.parent / "chip_smoke.py"
+    spec = importlib.util.spec_from_file_location("chip_smoke", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    assert mod.main([]) == 2
+    out, err = capsys.readouterr()
+    assert out == "" and "not 'tpu'" in err
 
 
 @pytest.mark.slow
