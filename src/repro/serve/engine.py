@@ -686,10 +686,10 @@ class PoolEngine:
         # dispatch the cheaper plain decode step instead of the chunk step.
         # That never changes served tokens only where the two step bodies
         # are bit-equal on decode rows, which is pinned on the CPU
-        # (tests/conformance).  On a TPU v5e they are not: XLA runs the
-        # attention dots of one query row on the vector unit and those of
-        # a chunk on the MXU, and pool output stopped matching solo
-        # output (PERF.md).
+        # (tests/conformance).  On a TPU v5e they are not: there the
+        # layer norm of one row reduces in another order than the same
+        # row inside a chunk, and pool output stopped matching solo
+        # output with the fast path in place (PERF.md).
         # Windowed archs keep the chunk step everywhere (its in-chunk
         # ring-wrap concat layout differs from decode's scatter).
         self._fast_decode = (cfg.window is None
