@@ -32,6 +32,12 @@ TPU kernel (repro.kernels.ops) instead of jnp — same math, fused quantize
 — and the backward through ``ops.potq_grad_matmuls``: G quantized once in
 VMEM, transposed operands expressed as BlockSpec index maps (no ``.T``
 copies), PRC clip-mask + dgamma reduction fused as the kernel epilogue.
+
+Every ALS-PoTQ pass outside the kernels (``_quantize_w``, ``_quantize_a``,
+``_quantize_g``, ``_qact``) runs under the ``als_potq`` name scope, which
+the profiler records in each device operation's name, so a trace tells
+the quantizers' time from the MF-MAC kernels' (``quantize_share`` in
+PERF.md).
 """
 from __future__ import annotations
 
@@ -59,18 +65,20 @@ def _maybe_pallas_matmul(x: jax.Array, y: jax.Array, policy: QuantPolicy) -> jax
 
 
 def _quantize_w(w: jax.Array, policy: QuantPolicy, axes=None) -> jax.Array:
-    if policy.weights_prequantized:
-        return w.astype(_BF16)  # already exact PoT values (serving path)
-    w = w.astype(jnp.float32)
-    if policy.weight_bias_correction:
-        if axes is None:
-            w = w - jnp.mean(w)
-        else:
-            w = w - jnp.mean(w, axis=axes, keepdims=True)
-    beta = potq.compute_beta(w, policy.bits_w, axes)
-    # bf16 is EXACT for PoT values (DESIGN.md §2); materializing quantized
-    # operands at 2 bytes halves FSDP gather traffic and remat residuals.
-    return potq.pot_quantize(w, policy.bits_w, beta).astype(_BF16)
+    with jax.named_scope("als_potq"):
+        if policy.weights_prequantized:
+            return w.astype(_BF16)  # already exact PoT values (serving path)
+        w = w.astype(jnp.float32)
+        if policy.weight_bias_correction:
+            if axes is None:
+                w = w - jnp.mean(w)
+            else:
+                w = w - jnp.mean(w, axis=axes, keepdims=True)
+        beta = potq.compute_beta(w, policy.bits_w, axes)
+        # bf16 is EXACT for PoT values (DESIGN.md §2); materializing
+        # quantized operands at 2 bytes halves FSDP gather traffic and remat
+        # residuals.
+        return potq.pot_quantize(w, policy.bits_w, beta).astype(_BF16)
 
 
 def _sample_axes(policy: QuantPolicy, x: jax.Array, axes):
@@ -85,27 +93,29 @@ def _sample_axes(policy: QuantPolicy, x: jax.Array, axes):
 
 def _quantize_a(a: jax.Array, gamma: jax.Array, policy: QuantPolicy, axes=None):
     """Returns (a_clipped_for_vjp_inputs_unchanged, aq)."""
-    axes = _sample_axes(policy, a, axes)
-    a32 = a.astype(jnp.float32)
-    if policy.prc_enabled:
-        if axes is None:
-            t = jax.lax.stop_gradient(jnp.max(jnp.abs(a32))) * gamma
+    with jax.named_scope("als_potq"):
+        axes = _sample_axes(policy, a, axes)
+        a32 = a.astype(jnp.float32)
+        if policy.prc_enabled:
+            if axes is None:
+                t = jax.lax.stop_gradient(jnp.max(jnp.abs(a32))) * gamma
+            else:
+                t = jax.lax.stop_gradient(
+                    jnp.max(jnp.abs(a32), axis=axes, keepdims=True)
+                ) * gamma
+            a_c = jnp.clip(a32, -t, t)
         else:
-            t = jax.lax.stop_gradient(
-                jnp.max(jnp.abs(a32), axis=axes, keepdims=True)
-            ) * gamma
-        a_c = jnp.clip(a32, -t, t)
-    else:
-        a_c = a32
-    beta = potq.compute_beta(a_c, policy.bits_a, axes)
-    return potq.pot_quantize(a_c, policy.bits_a, beta).astype(_BF16)
+            a_c = a32
+        beta = potq.compute_beta(a_c, policy.bits_a, axes)
+        return potq.pot_quantize(a_c, policy.bits_a, beta).astype(_BF16)
 
 
 def _quantize_g(g: jax.Array, policy: QuantPolicy, is_last: bool, axes=None):
-    g32 = g.astype(jnp.float32)
-    bits = policy.bits_g_last if is_last else policy.bits_g
-    beta = potq.compute_beta(g32, bits, axes)
-    return potq.pot_quantize(g32, bits, beta).astype(_BF16)
+    with jax.named_scope("als_potq"):
+        g32 = g.astype(jnp.float32)
+        bits = policy.bits_g_last if is_last else policy.bits_g
+        beta = potq.compute_beta(g32, bits, axes)
+        return potq.pot_quantize(g32, bits, beta).astype(_BF16)
 
 
 # ---------------------------------------------------------------------------
@@ -320,10 +330,11 @@ def _mf_act_dot(policy: QuantPolicy, dn, x, y):
 
 
 def _qact(x, bits, axes=None):
-    x32 = x.astype(jnp.float32)
-    return potq.pot_quantize(
-        x32, bits, potq.compute_beta(x32, bits, axes)
-    ).astype(_BF16)
+    with jax.named_scope("als_potq"):
+        x32 = x.astype(jnp.float32)
+        return potq.pot_quantize(
+            x32, bits, potq.compute_beta(x32, bits, axes)
+        ).astype(_BF16)
 
 
 def _mf_act_dot_fwd(policy, dn, x, y):

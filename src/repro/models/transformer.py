@@ -457,10 +457,15 @@ def _page_view(leaf, table, span):
     (P, page, ...): gather the slot tables, flatten pages back into a
     span.  Out-of-bounds table entries (serve.slots.drop_id — retired /
     unallocated slots) clamp onto the null page, whose ``pos`` is -1, so
-    everything they contribute is masked out of attention."""
-    b = table.shape[0]
-    x = leaf[table]  # (B, n, page, ...)
-    return x.reshape((b, span) + x.shape[3:])
+    everything they contribute is masked out of attention.
+
+    The gather, like every write through a page table, runs under the
+    ``paged_kv`` name scope, which a profile reads as the paged K/V
+    path's device time (``paged_kv_share`` in PERF.md)."""
+    with jax.named_scope("paged_kv"):
+        b = table.shape[0]
+        x = leaf[table]  # (B, n, page, ...)
+        return x.reshape((b, span) + x.shape[3:])
 
 
 def _kv_check(policy, cache):
@@ -480,12 +485,14 @@ def _kv_scatter(ck, cb, vals, dest, loff, spec):
     """Scatter freshly computed K or V vectors (B[, C], KV, hd) into a
     physical page store at (dest, loff) — PoT-encoding them (and their
     per-token betas into ``cb``) when ``spec`` is set."""
-    if spec is None:
-        return ck.at[dest, loff].set(vals.astype(ck.dtype), mode="drop"), cb
-    codes, beta = compress.kv_page_encode(vals, spec)
-    ck = ck.at[dest, loff].set(codes, mode="drop")
-    cb = cb.at[dest, loff].set(beta, mode="drop")
-    return ck, cb
+    with jax.named_scope("paged_kv"):
+        if spec is None:
+            return (ck.at[dest, loff].set(vals.astype(ck.dtype), mode="drop"),
+                    cb)
+        codes, beta = compress.kv_page_encode(vals, spec)
+        ck = ck.at[dest, loff].set(codes, mode="drop")
+        cb = cb.at[dest, loff].set(beta, mode="drop")
+        return ck, cb
 
 
 def _kv_page_view(ck, cb, table, span, spec, dtype):
@@ -494,11 +501,12 @@ def _kv_page_view(ck, cb, table, span, spec, dtype):
     fixed-order ``_sdpa`` reductions unchanged: exact-PoT operands in the
     highest-precision dot ARE the MF-MAC shift-add datapath (the same
     realization the weight path uses — docs/DESIGN_kernels.md)."""
-    view = _page_view(ck, table, span)
-    if spec is None:
-        return view.astype(dtype)
-    bview = _page_view(cb, table, span)
-    return compress.kv_page_decode(view, bview, spec).astype(dtype)
+    with jax.named_scope("paged_kv"):
+        view = _page_view(ck, table, span)
+        if spec is None:
+            return view.astype(dtype)
+        bview = _page_view(cb, table, span)
+        return compress.kv_page_decode(view, bview, spec).astype(dtype)
 
 
 def decode_step(cfg, policy, params, token, cache):
@@ -541,11 +549,13 @@ def decode_step(cfg, policy, params, token, cache):
     rows = jnp.arange(b)
     if paged:
         qpos = pos[:, None].astype(jnp.int32)  # (B, 1)
-        # physical write target; drop_id rows (dead slots) scatter-drop
-        dest = jnp.take_along_axis(table, (slot // page)[:, None], 1)[:, 0]
-        loff = slot % page
-        kpos_new = cache["pos"].at[dest, loff].set(pos, mode="drop")
-        kpos_view = _page_view(kpos_new, table, span)  # (B, span)
+        with jax.named_scope("paged_kv"):
+            # physical write target; drop_id rows (dead slots) scatter-drop
+            dest = jnp.take_along_axis(table, (slot // page)[:, None],
+                                       1)[:, 0]
+            loff = slot % page
+            kpos_new = cache["pos"].at[dest, loff].set(pos, mode="drop")
+            kpos_view = _page_view(kpos_new, table, span)  # (B, span)
         pq = qpos
     elif per_slot:
         qpos = pos[:, None].astype(jnp.int32)  # (B, 1)
@@ -678,15 +688,16 @@ def chunk_step(cfg, policy, params, tokens, n_new, cache):
     if paged:
         # physical (page, offset) write target per valid position; pads
         # route through an extra all-drop table column
-        table_ext = jnp.concatenate(
-            [table, jnp.full((b, 1), drop, table.dtype)], axis=1
-        )
-        lpage = jnp.where(valid, lo // page, npg)
-        dest = jnp.take_along_axis(table_ext, lpage, axis=1)  # (B, C)
-        loff = lo % page
-        kpos_old = _page_view(cache["pos"], table, span)  # (B, span)
-        kpos_new = cache["pos"].at[dest, loff].set(qpos, mode="drop")
-        kpos_view = _page_view(kpos_new, table, span)
+        with jax.named_scope("paged_kv"):
+            table_ext = jnp.concatenate(
+                [table, jnp.full((b, 1), drop, table.dtype)], axis=1
+            )
+            lpage = jnp.where(valid, lo // page, npg)
+            dest = jnp.take_along_axis(table_ext, lpage, axis=1)  # (B, C)
+            loff = lo % page
+            kpos_old = _page_view(cache["pos"], table, span)  # (B, span)
+            kpos_new = cache["pos"].at[dest, loff].set(qpos, mode="drop")
+            kpos_view = _page_view(kpos_new, table, span)
     else:
         sidx = jnp.where(valid, lo, span)
         kpos_old = cache["pos"]  # (B, span), pre-step — all entries < pos0

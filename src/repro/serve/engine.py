@@ -50,6 +50,7 @@ from typing import Dict, List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ModelConfig
 from repro.core import compress
@@ -891,7 +892,15 @@ class PoolEngine:
         continuation and patch the staged buffer (decode rows, fresh
         admissions) before the next dispatch.  The staged rows are
         byte-identical to the rows the synchronous loop would build, so
-        overlap changes wall-clock only — never tokens or counters."""
+        overlap changes wall-clock only — never tokens or counters.
+
+        Under a ``jax.profiler`` session the call leaves ``serve.*`` host
+        spans in the trace (docs/DESIGN_serving.md, "Tracing a call"); with
+        no session they cost nothing measurable."""
+        with TraceAnnotation("serve.run", requests=len(requests)):
+            return self._run(requests)
+
+    def _run(self, requests: Sequence[Request]) -> Dict:
         self._validate(requests)
         sched = FIFOScheduler(self.max_slots)
         for r in requests:
@@ -985,316 +994,366 @@ class PoolEngine:
                 self._prefill = make_prefill_step(self.cfg, self.policy)
             if self.spec is not None and self._spec_snap is None:
                 self._build_spec_steps()  # plan mode: build inside the ctx
-            cache = registry.init_pool_cache(
-                self.cfg, self.max_slots, self.max_len, self.cache_dtype,
-                **({"page_size": self.page_size, "num_pages": self.num_pages,
-                    "kv_quant": self.kv_quant}
-                   if self.paged else {}),
-            )
-            if alloc is not None:
-                # engine-managed pool: the allocator owns every mapping, so
-                # void the identity table init — dead slots must scatter
-                # into nothing, not into pages the allocator will hand out
-                cache = dict(cache)
-                cache["table"] = jnp.full(
-                    (self.max_slots, self.pages_per_slot),
-                    slots_lib.drop_id(self.num_pages), jnp.int32,
+            with TraceAnnotation("serve.init_cache"):
+                cache = registry.init_pool_cache(
+                    self.cfg, self.max_slots, self.max_len, self.cache_dtype,
+                    **({"page_size": self.page_size,
+                        "num_pages": self.num_pages,
+                        "kv_quant": self.kv_quant}
+                       if self.paged else {}),
                 )
-            while not sched.all_done():
-                stamp_arrivals()
-                dead_rows: List[int] = []
                 if alloc is not None:
-                    alloc.tick(step)
-                for slot, req in sched.admit(
-                    step, can_admit_cb if alloc is not None else None
-                ):
-                    stats.prompt_tokens += int(
-                        jnp.asarray(req.tokens).shape[-1]
-                    )
-                    if track_hist:
-                        histories[slot] = np.asarray(
-                            req.tokens, np.int64
-                        ).reshape(-1).tolist()
-                    aplan = None
-                    if alloc is not None:
-                        aplan, hold = holds.pop(0)
-                        alloc.bind(slot, hold)
-                        cache = self._sync_admission(cache, slot, hold, aplan)
-                        stats.prefix_hit_tokens += aplan.hit_tokens
-                    if self._chunkable(req):
-                        cache = self._admit_chunked(
-                            cache, slot, req, reset=alloc is None
-                        )
-                        if self.cfg.family == "encdec":
-                            stats.weight_passes += 1  # encoder-side pass
-                        sched.mark_prefilling(slot)
-                        prompt = np.asarray(req.tokens, np.int32).reshape(-1)
-                        prompts[slot] = prompt
-                        resume = aplan.resume if aplan is not None else 0
-                        pending[slot] = prompt[resume:]
-                    else:
-                        pages = hold["table"] if alloc is not None else None
-                        if pages is not None:
-                            pages = pages + [
-                                slots_lib.drop_id(self.num_pages)
-                            ] * (self.pages_per_slot - len(pages))
-                        cache, tok = self._prefill_into(
-                            cache, slot, req, pages=pages
-                        )
-                        stats.prefills += 1
-                        stats.weight_passes += 1
-                        first_token(slot, req, tok)
-                active = sched.active_slots()
-                prefilling = sched.prefilling_slots()
-                if not active and not prefilling:
-                    # Fast-forward the clock to the next arrival instead of
-                    # spinning empty decode steps.
-                    if dead_rows:
-                        cache = self._void_table_rows(cache, dead_rows)
-                    nxt = sched.next_arrival()
-                    if nxt is None:
-                        break
-                    step = max(step + 1, nxt)
-                    continue
-                if spec_dropped:
-                    # re-bind table entries lazily dropped by spec rollback
-                    # (wholly-rejected pages) before anything writes through
-                    # them again; numerically a no-op — their restored pos
-                    # is the -1 sentinel, masked either way
+                    # engine-managed pool: the allocator owns every mapping,
+                    # so void the identity table init — dead slots must
+                    # scatter into nothing, not into pages the allocator
+                    # will hand out
                     cache = dict(cache)
-                    tbl = cache["table"]
-                    for slot, cols in spec_dropped.items():
-                        row = alloc.tables[slot]
-                        for lp in sorted(cols):
-                            if lp < len(row):
-                                tbl = tbl.at[slot, lp].set(int(row[lp]))
-                    cache["table"] = tbl
-                    spec_dropped.clear()
-                if self.spec is not None and active and not prefilling:
-                    # Speculative round: draft -> one verify pass -> accept.
-                    # Greedy argmax acceptance emits exactly the tokens
-                    # sequential pooled decode would (verify_step is
-                    # bit-identical to decode_step per position), so
-                    # speculation only changes the weight-pass count.
-                    spec = self.spec
-                    c = spec.max_draft + 1
-                    lens = np.asarray(cache["len"])
-                    snap = self._spec_snap(cache)
-                    drafts: Dict[int, np.ndarray] = {}
-                    if spec.needs_draft_pass:
-                        dtoks, cache = self._draft(
-                            self.params, jnp.asarray(last_tok), cache
-                        )
-                        stats.draft_weight_passes += spec.max_draft
-                        # erase the draft's K/V + pos pollution: the verify
-                        # pass must see the pristine pre-round cache (ring
-                        # wraps near the span end and windowed evictions
-                        # would otherwise hide keys decode would attend to)
-                        cache = self._spec_restore(
-                            cache, snap,
-                            jnp.zeros((self.max_slots,), jnp.int32),
-                        )
-                        dhost = np.asarray(dtoks)
-                        for slot in active:
-                            drafts[slot] = dhost[slot]
-                    else:
-                        for slot in active:
-                            drafts[slot] = spec.propose(
-                                histories[slot], spec.max_draft
+                    cache["table"] = jnp.full(
+                        (self.max_slots, self.pages_per_slot),
+                        slots_lib.drop_id(self.num_pages), jnp.int32,
+                    )
+            while not sched.all_done():
+                with TraceAnnotation("serve.step", step=step):
+                    stamp_arrivals()
+                    dead_rows: List[int] = []
+                    if alloc is not None:
+                        alloc.tick(step)
+                    for slot, req in sched.admit(
+                        step, can_admit_cb if alloc is not None else None
+                    ):
+                        with TraceAnnotation("serve.admit", uid=req.uid,
+                                             step=step):
+                            stats.prompt_tokens += int(
+                                jnp.asarray(req.tokens).shape[-1]
                             )
-                    tokens = np.zeros((self.max_slots, c), np.int32)
-                    n_new = np.zeros((self.max_slots,), np.int32)
-                    for slot in active:
-                        cap = remaining[slot]
-                        if self.cfg.window is None:
-                            # a verify row's valid positions may not wrap
-                            # the span ring (windowed archs wrap by design)
-                            cap = min(cap, self.span - int(lens[slot]))
-                        nd = max(0, min(len(drafts[slot]), cap - 1, c - 1))
-                        tokens[slot, 0] = last_tok[slot]
-                        if nd:
-                            tokens[slot, 1:1 + nd] = drafts[slot][:nd]
-                        n_new[slot] = 1 + nd
-                    if int(n_new.max()) > 1:
-                        vtok, _, cache = self._verify(
-                            self.params, jnp.asarray(tokens),
-                            jnp.asarray(n_new), cache,
-                        )
-                        stats.decode_steps += 1
-                        stats.weight_passes += 1
-                        stats.occupancy_sum += len(active) / self.max_slots
-                        if alloc is not None:
-                            stats.pages_in_use_sum += alloc.pages_in_use()
-                        vhost = np.asarray(vtok)
-                        keep = np.zeros((self.max_slots,), np.int32)
-                        for slot in active:
-                            req = sched.active_request(slot)
-                            nd = int(n_new[slot]) - 1
-                            a = spec_lib.greedy_accept(
-                                tokens[slot, 1:1 + nd], vhost[slot, :nd]
-                            )
-                            # accepted drafts + the verifier's bonus token,
-                            # then exactly sequential decode's stop rules:
-                            # cut at the first EOS, cap at the budget
-                            emit = [int(t) for t in tokens[slot, 1:1 + a]]
-                            emit.append(int(vhost[slot, a]))
-                            for j, t in enumerate(emit):
-                                if t == req.eos_id:
-                                    emit = emit[:j + 1]
-                                    break
-                            emit = emit[:remaining[slot]]
-                            keep[slot] = len(emit)
-                            stats.accepted_tokens += len(emit) - 1
-                            out[req.uid].extend(emit)
                             if track_hist:
-                                histories[slot].extend(emit)
-                            stats.emitted_tokens += len(emit)
-                            last_tok[slot] = emit[-1]
-                            remaining[slot] -= len(emit)
-                            if remaining[slot] <= 0 or emit[-1] == req.eos_id:
-                                retire(slot)
-                        # roll back the rejected tail: keep[slot] kept
-                        # positions cache exactly the consumed context (the
-                        # last emitted token is never cached, as in decode)
-                        cache = self._spec_restore(
-                            cache, snap, jnp.asarray(keep)
-                        )
-                        if alloc is not None and self.cfg.window is None:
-                            # wholly-rejected pages: table entries ->
-                            # drop_id (pos already back at the -1 sentinel);
-                            # re-bound from alloc.tables before next write
-                            drop = slots_lib.drop_id(self.num_pages)
-                            didx = []
-                            for slot in active:
-                                if slot in dead_rows:
-                                    continue
-                                p0 = int(lens[slot])
-                                lo = -(-(p0 + int(keep[slot]))
-                                       // self.page_size)
-                                hi = (p0 + int(n_new[slot]) - 1) \
-                                    // self.page_size
-                                nmap = len(alloc.tables[slot])
-                                cols = [lp for lp in range(lo, hi + 1)
-                                        if lp < nmap]
-                                if cols:
-                                    spec_dropped.setdefault(
-                                        slot, set()
-                                    ).update(cols)
-                                    didx += [(slot, lp) for lp in cols]
-                            if didx:
-                                cache = dict(cache)
-                                rr = jnp.asarray([s for s, _ in didx],
-                                                 jnp.int32)
-                                cc = jnp.asarray([l for _, l in didx],
-                                                 jnp.int32)
-                                cache["table"] = (
-                                    cache["table"].at[rr, cc].set(drop)
+                                histories[slot] = np.asarray(
+                                    req.tokens, np.int64
+                                ).reshape(-1).tolist()
+                            aplan = None
+                            if alloc is not None:
+                                aplan, hold = holds.pop(0)
+                                alloc.bind(slot, hold)
+                                cache = self._sync_admission(
+                                    cache, slot, hold, aplan
                                 )
+                                stats.prefix_hit_tokens += aplan.hit_tokens
+                            if self._chunkable(req):
+                                cache = self._admit_chunked(
+                                    cache, slot, req, reset=alloc is None
+                                )
+                                if self.cfg.family == "encdec":
+                                    # encoder-side pass
+                                    stats.weight_passes += 1
+                                sched.mark_prefilling(slot)
+                                prompt = np.asarray(
+                                    req.tokens, np.int32
+                                ).reshape(-1)
+                                prompts[slot] = prompt
+                                resume = (aplan.resume if aplan is not None
+                                          else 0)
+                                pending[slot] = prompt[resume:]
+                            else:
+                                pages = (hold["table"] if alloc is not None
+                                         else None)
+                                if pages is not None:
+                                    pages = pages + [
+                                        slots_lib.drop_id(self.num_pages)
+                                    ] * (self.pages_per_slot - len(pages))
+                                cache, tok = self._prefill_into(
+                                    cache, slot, req, pages=pages
+                                )
+                                stats.prefills += 1
+                                stats.weight_passes += 1
+                                first_token(slot, req, tok)
+                    active = sched.active_slots()
+                    prefilling = sched.prefilling_slots()
+                    if not active and not prefilling:
+                        # Fast-forward the clock to the next arrival instead of
+                        # spinning empty decode steps.
                         if dead_rows:
                             cache = self._void_table_rows(cache, dead_rows)
+                        nxt = sched.next_arrival()
+                        if nxt is None:
+                            break
+                        step = max(step + 1, nxt)
+                        continue
+                    if spec_dropped:
+                        # re-bind table entries lazily dropped by spec
+                        # rollback (wholly-rejected pages) before anything
+                        # writes through them again; numerically a no-op —
+                        # their restored pos is the -1 sentinel, masked
+                        # either way
+                        with TraceAnnotation("serve.dispatch", step=step):
+                            cache = dict(cache)
+                            tbl = cache["table"]
+                            for slot, cols in spec_dropped.items():
+                                row = alloc.tables[slot]
+                                for lp in sorted(cols):
+                                    if lp < len(row):
+                                        tbl = tbl.at[slot, lp].set(
+                                            int(row[lp])
+                                        )
+                            cache["table"] = tbl
+                            spec_dropped.clear()
+                    if self.spec is not None and active and not prefilling:
+                        # Speculative round: draft -> one verify pass ->
+                        # accept.  Greedy argmax acceptance emits exactly the
+                        # tokens sequential pooled decode would (verify_step
+                        # is bit-identical to decode_step per position), so
+                        # speculation only changes the weight-pass count.
+                        spec = self.spec
+                        c = spec.max_draft + 1
+                        with TraceAnnotation("serve.dispatch", step=step):
+                            lens = np.asarray(cache["len"])
+                            snap = self._spec_snap(cache)
+                            drafts: Dict[int, np.ndarray] = {}
+                            if spec.needs_draft_pass:
+                                dtoks, cache = self._draft(
+                                    self.params, jnp.asarray(last_tok), cache
+                                )
+                                stats.draft_weight_passes += spec.max_draft
+                                # erase the draft's K/V + pos pollution: the
+                                # verify pass must see the pristine pre-round
+                                # cache (ring wraps near the span end and
+                                # windowed evictions would otherwise hide keys
+                                # decode would attend to)
+                                cache = self._spec_restore(
+                                    cache, snap,
+                                    jnp.zeros((self.max_slots,), jnp.int32),
+                                )
+                                dhost = np.asarray(dtoks)
+                                for slot in active:
+                                    drafts[slot] = dhost[slot]
+                            else:
+                                for slot in active:
+                                    drafts[slot] = spec.propose(
+                                        histories[slot], spec.max_draft
+                                    )
+                            tokens = np.zeros((self.max_slots, c), np.int32)
+                            n_new = np.zeros((self.max_slots,), np.int32)
+                            for slot in active:
+                                cap = remaining[slot]
+                                if self.cfg.window is None:
+                                    # a verify row's valid positions may not
+                                    # wrap the span ring (windowed archs wrap
+                                    # by design)
+                                    cap = min(cap, self.span - int(lens[slot]))
+                                nd = max(0, min(len(drafts[slot]), cap - 1,
+                                                c - 1))
+                                tokens[slot, 0] = last_tok[slot]
+                                if nd:
+                                    tokens[slot, 1:1 + nd] = drafts[slot][:nd]
+                                n_new[slot] = 1 + nd
+                            verify = int(n_new.max()) > 1
+                            if verify:
+                                vtok, _, cache = self._verify(
+                                    self.params, jnp.asarray(tokens),
+                                    jnp.asarray(n_new), cache,
+                                )
+                                stats.decode_steps += 1
+                                stats.weight_passes += 1
+                                stats.occupancy_sum += (
+                                    len(active) / self.max_slots
+                                )
+                                if alloc is not None:
+                                    stats.pages_in_use_sum += (
+                                        alloc.pages_in_use()
+                                    )
+                        if verify:
+                            with TraceAnnotation("serve.wait"):
+                                vhost = np.asarray(vtok)
+                            with TraceAnnotation("serve.retire"):
+                                keep = np.zeros((self.max_slots,), np.int32)
+                                for slot in active:
+                                    req = sched.active_request(slot)
+                                    nd = int(n_new[slot]) - 1
+                                    a = spec_lib.greedy_accept(
+                                        tokens[slot, 1:1 + nd],
+                                        vhost[slot, :nd],
+                                    )
+                                    # accepted drafts + the verifier's bonus
+                                    # token, then exactly sequential decode's
+                                    # stop rules: cut at the first EOS, cap at
+                                    # the budget
+                                    emit = [int(t)
+                                            for t in tokens[slot, 1:1 + a]]
+                                    emit.append(int(vhost[slot, a]))
+                                    for j, t in enumerate(emit):
+                                        if t == req.eos_id:
+                                            emit = emit[:j + 1]
+                                            break
+                                    emit = emit[:remaining[slot]]
+                                    keep[slot] = len(emit)
+                                    stats.accepted_tokens += len(emit) - 1
+                                    out[req.uid].extend(emit)
+                                    if track_hist:
+                                        histories[slot].extend(emit)
+                                    stats.emitted_tokens += len(emit)
+                                    last_tok[slot] = emit[-1]
+                                    remaining[slot] -= len(emit)
+                                    if (remaining[slot] <= 0
+                                            or emit[-1] == req.eos_id):
+                                        retire(slot)
+                                # roll back the rejected tail: keep[slot]
+                                # kept positions cache exactly the consumed
+                                # context (the last emitted token is never
+                                # cached, as in decode)
+                                cache = self._spec_restore(
+                                    cache, snap, jnp.asarray(keep)
+                                )
+                                if (alloc is not None
+                                        and self.cfg.window is None):
+                                    # wholly-rejected pages: table entries ->
+                                    # drop_id (pos already back at the -1
+                                    # sentinel); re-bound from alloc.tables
+                                    # before next write
+                                    drop = slots_lib.drop_id(self.num_pages)
+                                    didx = []
+                                    for slot in active:
+                                        if slot in dead_rows:
+                                            continue
+                                        p0 = int(lens[slot])
+                                        lo = -(-(p0 + int(keep[slot]))
+                                               // self.page_size)
+                                        hi = (p0 + int(n_new[slot]) - 1) \
+                                            // self.page_size
+                                        nmap = len(alloc.tables[slot])
+                                        cols = [lp for lp in range(lo, hi + 1)
+                                                if lp < nmap]
+                                        if cols:
+                                            spec_dropped.setdefault(
+                                                slot, set()
+                                            ).update(cols)
+                                            didx += [(slot, lp) for lp in cols]
+                                    if didx:
+                                        cache = dict(cache)
+                                        rr = jnp.asarray([s for s, _ in didx],
+                                                         jnp.int32)
+                                        cc = jnp.asarray([l for _, l in didx],
+                                                         jnp.int32)
+                                        cache["table"] = (
+                                            cache["table"].at[rr, cc].set(drop)
+                                        )
+                                if dead_rows:
+                                    cache = self._void_table_rows(
+                                        cache, dead_rows
+                                    )
+                            with TraceAnnotation("serve.check"):
+                                sched.check_conservation()
+                                if alloc is not None:
+                                    alloc.check_conservation()
+                            step += 1
+                            continue
+                        # no slot had a draft: the cache is pristine (any
+                        # self-draft pollution was restored above), so fall
+                        # through to the plain fixed-shape dispatch
+                    finishing = []
+                    with TraceAnnotation("serve.dispatch", step=step):
+                        if chunk is None or (self._fast_decode
+                                             and not prefilling):
+                            ntok, _, cache = self._decode(
+                                self.params, jnp.asarray(last_tok), cache
+                            )
+                        else:
+                            tokens = np.zeros((self.max_slots, chunk),
+                                              np.int32)
+                            n_new = np.zeros((self.max_slots,), np.int32)
+                            for slot in active:
+                                tokens[slot, 0] = last_tok[slot]
+                                n_new[slot] = 1
+                            for slot in prefilling:
+                                if slot in staged:
+                                    # double-buffered: this row was staged
+                                    # while the previous step was in flight
+                                    row, take, fin = staged.pop(slot)
+                                    tokens[slot] = row
+                                    n_new[slot] = take
+                                    if fin:
+                                        finishing.append(slot)
+                                    continue
+                                buf = pending[slot]
+                                take = min(chunk, len(buf))
+                                tokens[slot, :take] = buf[:take]
+                                n_new[slot] = take
+                                pending[slot] = buf[take:]
+                                if take == len(buf):
+                                    finishing.append(slot)
+                            ntok, _, cache = self._chunk_step(
+                                self.params, jnp.asarray(tokens),
+                                jnp.asarray(n_new), cache,
+                            )
+                        flight = _InflightTokens(ntok)
+                        stats.decode_steps += 1
+                        stats.weight_passes += 1
+                        stats.occupancy_sum += (
+                            (len(active) + len(prefilling)) / self.max_slots
+                        )
+                        if alloc is not None:
+                            stats.pages_in_use_sum += alloc.pages_in_use()
+                    # -- overlap window: the jitted step (and the async host
+                    # copy of its token vector) is in flight.  Host-side
+                    # scheduling overlaps the step: next-step arrivals stamp
+                    # against the already-bumped pass clock (identical to
+                    # stamping at the top of the next iteration — no weight
+                    # pass can land in between), and every slot that STAYS
+                    # prefilling gets its next chunk row staged now.  Neither
+                    # depends on this step's tokens: finishing slots are known
+                    # at dispatch (their whole prompt is consumed) and
+                    # prefilling slots are never retired, so the staging
+                    # buffer can't be invalidated by the retirements the
+                    # arriving tokens trigger.
+                    with TraceAnnotation("serve.overlap"):
+                        stamp_arrivals(step + 1)
+                        if chunk is not None:
+                            for slot in prefilling:
+                                if slot in finishing:
+                                    # the next row needs this step's token
+                                    continue
+                                buf = pending[slot]
+                                take = min(chunk, len(buf))
+                                row = np.zeros((chunk,), np.int32)
+                                row[:take] = buf[:take]
+                                staged[slot] = (row, take, take == len(buf))
+                                pending[slot] = buf[take:]
+                    # -- synchronize: tokens arrive; retirement and the
+                    # admissions it unblocks run as the copy's continuation --
+                    with TraceAnnotation("serve.wait"):
+                        ntok_host = flight.wait()
+                    with TraceAnnotation("serve.retire"):
+                        for slot in finishing:
+                            sched.finish_prefill(slot)
+                            stats.prefills += 1
+                            if (alloc is not None and self.prefix_cache
+                                    and self.cfg.window is None):
+                                # publish the finished prompt's full pages for
+                                # reuse BEFORE first_token may retire the slot
+                                alloc.register_prefix(slot, prompts[slot],
+                                                      chunk)
+                            first_token(slot, sched.active_request(slot),
+                                        int(ntok_host[slot]))
+                        for slot in active:
+                            req = sched.active_request(slot)
+                            tok = int(ntok_host[slot])
+                            out[req.uid].append(tok)
+                            if track_hist:
+                                histories[slot].append(tok)
+                            last_tok[slot] = tok
+                            stats.emitted_tokens += 1
+                            remaining[slot] -= 1
+                            if remaining[slot] <= 0 or tok == req.eos_id:
+                                retire(slot)
+                        if dead_rows:
+                            # retired slots keep riding the fixed-shape
+                            # dispatch; void their table rows so their
+                            # scatters drop instead of landing in pages the
+                            # allocator may reassign
+                            cache = self._void_table_rows(cache, dead_rows)
+                    with TraceAnnotation("serve.check"):
                         sched.check_conservation()
                         if alloc is not None:
                             alloc.check_conservation()
-                        step += 1
-                        continue
-                    # no slot had a draft: the cache is pristine (any
-                    # self-draft pollution was restored above), so fall
-                    # through to the plain fixed-shape dispatch
-                finishing = []
-                if chunk is None or (self._fast_decode and not prefilling):
-                    ntok, _, cache = self._decode(
-                        self.params, jnp.asarray(last_tok), cache
-                    )
-                else:
-                    tokens = np.zeros((self.max_slots, chunk), np.int32)
-                    n_new = np.zeros((self.max_slots,), np.int32)
-                    for slot in active:
-                        tokens[slot, 0] = last_tok[slot]
-                        n_new[slot] = 1
-                    for slot in prefilling:
-                        if slot in staged:
-                            # double-buffered: this row was staged while
-                            # the previous step was in flight
-                            row, take, fin = staged.pop(slot)
-                            tokens[slot] = row
-                            n_new[slot] = take
-                            if fin:
-                                finishing.append(slot)
-                            continue
-                        buf = pending[slot]
-                        take = min(chunk, len(buf))
-                        tokens[slot, :take] = buf[:take]
-                        n_new[slot] = take
-                        pending[slot] = buf[take:]
-                        if take == len(buf):
-                            finishing.append(slot)
-                    ntok, _, cache = self._chunk_step(
-                        self.params, jnp.asarray(tokens),
-                        jnp.asarray(n_new), cache,
-                    )
-                # -- overlap window: the jitted step (and the async host
-                # copy of its token vector) is in flight ------------------
-                flight = _InflightTokens(ntok)
-                stats.decode_steps += 1
-                stats.weight_passes += 1
-                stats.occupancy_sum += (
-                    (len(active) + len(prefilling)) / self.max_slots
-                )
-                if alloc is not None:
-                    stats.pages_in_use_sum += alloc.pages_in_use()
-                # host-side scheduling overlaps the step: next-step arrivals
-                # stamp against the already-bumped pass clock (identical to
-                # stamping at the top of the next iteration — no weight pass
-                # can land in between), and every slot that STAYS prefilling
-                # gets its next chunk row staged now.  Neither depends on
-                # this step's tokens: finishing slots are known at dispatch
-                # (their whole prompt is consumed) and prefilling slots are
-                # never retired, so the staging buffer can't be invalidated
-                # by the retirements the arriving tokens trigger.
-                stamp_arrivals(step + 1)
-                if chunk is not None:
-                    for slot in prefilling:
-                        if slot in finishing:
-                            continue  # next row needs this step's token
-                        buf = pending[slot]
-                        take = min(chunk, len(buf))
-                        row = np.zeros((chunk,), np.int32)
-                        row[:take] = buf[:take]
-                        staged[slot] = (row, take, take == len(buf))
-                        pending[slot] = buf[take:]
-                # -- synchronize: tokens arrive; retirement and the
-                # admissions it unblocks run as the copy's continuation --
-                ntok_host = flight.wait()
-                for slot in finishing:
-                    sched.finish_prefill(slot)
-                    stats.prefills += 1
-                    if (alloc is not None and self.prefix_cache
-                            and self.cfg.window is None):
-                        # publish the finished prompt's full pages for
-                        # reuse BEFORE first_token may retire the slot
-                        alloc.register_prefix(slot, prompts[slot], chunk)
-                    first_token(slot, sched.active_request(slot),
-                                int(ntok_host[slot]))
-                for slot in active:
-                    req = sched.active_request(slot)
-                    tok = int(ntok_host[slot])
-                    out[req.uid].append(tok)
-                    if track_hist:
-                        histories[slot].append(tok)
-                    last_tok[slot] = tok
-                    stats.emitted_tokens += 1
-                    remaining[slot] -= 1
-                    if remaining[slot] <= 0 or tok == req.eos_id:
-                        retire(slot)
-                if dead_rows:
-                    # retired slots keep riding the fixed-shape dispatch;
-                    # void their table rows so their scatters drop instead
-                    # of landing in pages the allocator may reassign
-                    cache = self._void_table_rows(cache, dead_rows)
-                sched.check_conservation()
-                if alloc is not None:
-                    alloc.check_conservation()
-                step += 1
+                    step += 1
         if alloc is not None:
             stats.cow_copies = alloc.cow_copies
             stats.evictions = alloc.evictions

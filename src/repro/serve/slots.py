@@ -173,17 +173,6 @@ def page_pool_cache(cache, max_slots: int, page_size: int,
     return out
 
 
-def gather_view(pool, leaf):
-    """Logical (B, span, ...) view of one physical page store: gather the
-    slot tables, flatten the page axis back into a span axis.  Table
-    entries >= num_pages+1 clamp onto the null page (gather OOB
-    semantics), whose ``pos`` row is -1 — masked by attention."""
-    table = pool["table"]  # (B, n)
-    b, n = table.shape
-    x = leaf[table]  # (B, n, page, ...)
-    return x.reshape((b, n * x.shape[2]) + x.shape[3:])
-
-
 def reset_slot(pool, slot: int):
     """Rewind one slot for chunked-prefill admission: ``len`` -> 0 and its
     position bookkeeping to -1 (the not-yet-written sentinel the attention
