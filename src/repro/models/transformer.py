@@ -452,19 +452,54 @@ def prefill(cfg, policy, params, tokens, cache, patch_embeds=None):
     return logits[:, -1, :], cache
 
 
-def _page_view(leaf, table, span):
+def _layer_pages(store, layer, pages, *, write):
+    """Layer ``layer``'s ``pages`` of a stacked page store (L, P, page,
+    ...): ``(view, index)`` such that ``view[index]`` gathers them and
+    ``view.at[index + (offset,)]`` writes into them.
+
+    On one device the view is the store as one (L * P, page, ...) array
+    of pages, and a page past the layer's last (serve.slots.drop_id)
+    indexes past the whole array for a ``write``, which then drops, or
+    the layer's null page for a read, as an unstacked gather clamps it.
+    Indexed by (layer, page) pairs, the TPU keeps a store whose minor dim
+    is under 128 lanes (PoT code pages) in one layout for the scatter and
+    another for the gather, and copies the whole stack between them
+    every layer.  A pool sharded over its pages (an active actshard plan,
+    serve/engine.py) keeps the (layer, page) pairs: merging the sharded
+    page dim into the layer dim would gather the whole store onto every
+    device."""
+    if actshard.active_plan() is not None:
+        return store, (layer, pages)
+    n_layers, n_pages = store.shape[:2]
+    flat = store.reshape((n_layers * n_pages,) + store.shape[2:])
+    if write:
+        row = jnp.where(pages < n_pages, layer * n_pages + pages,
+                        n_layers * n_pages)
+    else:
+        row = layer * n_pages + jnp.minimum(pages, n_pages - 1)
+    return flat, (row,)
+
+
+def _page_view(leaf, table, span, layer=None):
     """Logical (B, span, ...) row view of a physical page store
     (P, page, ...): gather the slot tables, flatten pages back into a
     span.  Out-of-bounds table entries (serve.slots.drop_id — retired /
     unallocated slots) clamp onto the null page, whose ``pos`` is -1, so
-    everything they contribute is masked out of attention.
+    everything they contribute is masked out of attention.  With
+    ``layer`` the store is the stacked (L, P, page, ...) one, and the
+    layer's pages come out of it in one gather, without slicing the
+    layer's store out first.
 
     The gather, like every write through a page table, runs under the
     ``paged_kv`` name scope, which a profile reads as the paged K/V
     path's device time (``paged_kv_share`` in PERF.md)."""
     with jax.named_scope("paged_kv"):
         b = table.shape[0]
-        x = leaf[table]  # (B, n, page, ...)
+        if layer is None:
+            x = leaf[table]  # (B, n, page, ...)
+        else:
+            view, idx = _layer_pages(leaf, layer, table, write=False)
+            x = view[idx]
         return x.reshape((b, span) + x.shape[3:])
 
 
@@ -481,32 +516,70 @@ def _kv_check(policy, cache):
     return kvq
 
 
-def _kv_scatter(ck, cb, vals, dest, loff, spec):
+def _kv_scatter(ck, cb, vals, dest, loff, spec, layer=None):
     """Scatter freshly computed K or V vectors (B[, C], KV, hd) into a
     physical page store at (dest, loff) — PoT-encoding them (and their
-    per-token betas into ``cb``) when ``spec`` is set."""
+    per-token betas into ``cb``) when ``spec`` is set.  With ``layer``
+    the stores are the stacked ones and the write lands in that layer's
+    pages."""
+    def put(store, x):
+        if layer is None:
+            return store.at[dest, loff].set(x, mode="drop")
+        view, idx = _layer_pages(store, layer, dest, write=True)
+        return view.at[idx + (loff,)].set(x, mode="drop").reshape(store.shape)
+
     with jax.named_scope("paged_kv"):
         if spec is None:
-            return (ck.at[dest, loff].set(vals.astype(ck.dtype), mode="drop"),
-                    cb)
+            return put(ck, vals.astype(ck.dtype)), cb
         codes, beta = compress.kv_page_encode(vals, spec)
-        ck = ck.at[dest, loff].set(codes, mode="drop")
-        cb = cb.at[dest, loff].set(beta, mode="drop")
-        return ck, cb
+        return put(ck, codes), put(cb, beta)
 
 
-def _kv_page_view(ck, cb, table, span, spec, dtype):
+def _kv_page_view(ck, cb, table, span, spec, dtype, layer=None):
     """Gathered logical (B, span, KV, hd) K/V view, dequantized to exact
     PoT float values when ``spec`` is set.  Those values feed the existing
     fixed-order ``_sdpa`` reductions unchanged: exact-PoT operands in the
     highest-precision dot ARE the MF-MAC shift-add datapath (the same
     realization the weight path uses — docs/DESIGN_kernels.md)."""
     with jax.named_scope("paged_kv"):
-        view = _page_view(ck, table, span)
+        view = _page_view(ck, table, span, layer)
         if spec is None:
             return view.astype(dtype)
-        bview = _page_view(cb, table, span)
+        bview = _page_view(cb, table, span, layer)
         return compress.kv_page_decode(view, bview, spec).astype(dtype)
+
+
+#: The stacked (L, ...) K/V store leaves of a decode cache; the beta
+#: scale leaves exist only in the PoT-quantized page layout.
+_KV_STORES = ("k", "v", "k_beta", "v_beta")
+
+
+def _kv_stores(ck, cv, ckb=None, cvb=None):
+    return {n: a for n, a in zip(_KV_STORES, (ck, cv, ckb, cvb))
+            if a is not None}
+
+
+def _scan_layers(params, cache, x, layer):
+    """Run ``layer(y, lp, l, kv) -> (y, kv)`` over the stacked layers,
+    ``l`` the layer index and ``kv`` the cache's whole stacked K/V stores
+    (``_KV_STORES``).
+
+    The stores ride in the scan's carry, and each layer writes and
+    gathers its own pages at index ``l``.  As the scan's xs/ys instead,
+    every step would slice each layer's store out and stack it back —
+    two copies of the whole cache, whatever the few positions written.
+    With the cache donated to the step (serve/engine.py) XLA updates the
+    stores in place."""
+    kv = {n: cache[n] for n in _KV_STORES if n in cache}
+    idx = jax.lax.iota(jnp.int32, cache["k"].shape[0])
+
+    def body(carry, xs):
+        y, kv = carry
+        lp, l = xs
+        return layer(y, lp, l, kv), None
+
+    (x, kv), _ = jax.lax.scan(body, (x, kv), (params["layers"], idx))
+    return x, kv
 
 
 def decode_step(cfg, policy, params, token, cache):
@@ -570,9 +643,8 @@ def decode_step(cfg, policy, params, token, cache):
         kpos_view = kpos_new
         pq = jnp.broadcast_to(qpos[None, :], (b, 1))
 
-    def carry_block(carry, lp_kv):
-        lp, ck, cv, *betas = lp_kv
-        ckb, cvb = betas if kvq else (None, None)
+    def layer(carry, lp, l, kv):
+        ck, cv, ckb, cvb = (kv.get(n) for n in _KV_STORES)
         h = common.apply_norm(cfg.norm, carry, lp["ln1"])
         # project new token
         q = mfmac.mf_linear(h, lp["wq"]["w"], lp["wq"]["gamma"], policy=policy)
@@ -584,22 +656,22 @@ def decode_step(cfg, policy, params, token, cache):
         q = common.rope(q, pq, cfg.rope_theta)
         k = common.rope(k, pq, cfg.rope_theta)
         if paged:
-            ck, ckb = _kv_scatter(ck, ckb, k[:, 0], dest, loff, spec)
-            cv, cvb = _kv_scatter(cv, cvb, v[:, 0], dest, loff, spec)
-            kview = _kv_page_view(ck, ckb, table, span, spec, q.dtype)
-            vview = _kv_page_view(cv, cvb, table, span, spec, q.dtype)
-        elif per_slot:
-            ck = ck.at[rows, slot].set(k[:, 0].astype(ck.dtype))
-            cv = cv.at[rows, slot].set(v[:, 0].astype(cv.dtype))
-            kview, vview = ck.astype(q.dtype), cv.astype(q.dtype)
+            ck, ckb = _kv_scatter(ck, ckb, k[:, 0], dest, loff, spec, l)
+            cv, cvb = _kv_scatter(cv, cvb, v[:, 0], dest, loff, spec, l)
+            kview = _kv_page_view(ck, ckb, table, span, spec, q.dtype, l)
+            vview = _kv_page_view(cv, cvb, table, span, spec, q.dtype, l)
         else:
-            ck = jax.lax.dynamic_update_slice(
-                ck, k.astype(ck.dtype), (0, slot, 0, 0)
-            )
-            cv = jax.lax.dynamic_update_slice(
-                cv, v.astype(cv.dtype), (0, slot, 0, 0)
-            )
-            kview, vview = ck.astype(q.dtype), cv.astype(q.dtype)
+            if per_slot:
+                ck = ck.at[l, rows, slot].set(k[:, 0].astype(ck.dtype))
+                cv = cv.at[l, rows, slot].set(v[:, 0].astype(cv.dtype))
+            else:
+                ck = jax.lax.dynamic_update_slice(
+                    ck, k[None].astype(ck.dtype), (l, 0, slot, 0, 0)
+                )
+                cv = jax.lax.dynamic_update_slice(
+                    cv, v[None].astype(cv.dtype), (l, 0, slot, 0, 0)
+                )
+            kview, vview = ck[l].astype(q.dtype), cv[l].astype(q.dtype)
         att = _sdpa(cfg, policy, q, kview, vview, qpos, kpos_view, cfg.window)
         att = att.reshape(b, 1, cfg.n_heads * cfg.head_dim)
         y = carry + mfmac.mf_linear(
@@ -610,23 +682,12 @@ def decode_step(cfg, policy, params, token, cache):
             y = y + _moe_apply(cfg, policy, lp["moe"], h2, per_slot=True)
         else:
             y = y + _mlp_apply(cfg, policy, lp["mlp"], h2)
-        out = (ck, cv) + ((ckb, cvb) if kvq else ())
-        return y, out
+        return y, _kv_stores(ck, cv, ckb, cvb)
 
-    xs = (params["layers"], cache["k"], cache["v"])
-    if kvq:
-        xs = xs + (cache["k_beta"], cache["v_beta"])
-    x, scanned = jax.lax.scan(carry_block, x, xs)
+    x, kv = _scan_layers(params, cache, x, layer)
     x = common.apply_norm(cfg.norm, x, params["final_norm"])
     logits = _lm_head(cfg, policy, params, x)[:, 0, :]
-    new_cache = {
-        "k": scanned[0],
-        "v": scanned[1],
-        "pos": kpos_new,
-        "len": pos + 1,
-    }
-    if kvq:
-        new_cache["k_beta"], new_cache["v_beta"] = scanned[2], scanned[3]
+    new_cache = dict(kv, pos=kpos_new, len=pos + 1)
     if paged:
         new_cache["table"] = table
     return logits, new_cache
@@ -705,9 +766,8 @@ def chunk_step(cfg, policy, params, tokens, n_new, cache):
         kpos_view = kpos_new
     windowed = cfg.window is not None
 
-    def carry_block(carry, lp_kv):
-        lp, ck, cv, *betas = lp_kv
-        ckb, cvb = betas if kvq else (None, None)
+    def layer(carry, lp, l, kv):
+        ck, cv, ckb, cvb = (kv.get(n) for n in _KV_STORES)
         h = common.apply_norm(cfg.norm, carry, lp["ln1"])
         # Zero pad positions BEFORE the projections: each row's
         # activation-scale group is its (C, D) block, so with pads
@@ -723,35 +783,37 @@ def chunk_step(cfg, policy, params, tokens, n_new, cache):
         v = v.reshape(b, c, cfg.kv_heads, cfg.head_dim)
         q = common.rope(q, qpos, cfg.rope_theta)
         k = common.rope(k, qpos, cfg.rope_theta)
-        if paged:
-            nk, nkb = _kv_scatter(ck, ckb, k, dest, loff, spec)
-            nv, nvb = _kv_scatter(cv, cvb, v, dest, loff, spec)
-        else:
-            nk = ck.at[rows[:, None], sidx].set(k.astype(ck.dtype),
-                                                mode="drop")
-            nv = cv.at[rows[:, None], sidx].set(v.astype(cv.dtype),
-                                                mode="drop")
         if windowed:
             # attend over [old cache ∪ fresh chunk]: old entries hold
             # only positions < pos0, fresh ones >= pos0 (qpos -1 where
             # invalid), so the position mask sees each key exactly once
-            # even when the ring wraps mid-chunk.  In the quantized
-            # layout the fresh in-chunk K/V is re-read through the wire
-            # format (encode-then-decode) so every attended key is the
-            # same PoT value later steps will gather — the chunked
-            # admission must reproduce the incremental write paths bit
-            # for bit.
+            # even when the ring wraps mid-chunk.  The old view is
+            # gathered before the scatter below overwrites the stores.
+            # In the quantized layout the fresh in-chunk K/V is re-read
+            # through the wire format (encode-then-decode) so every
+            # attended key is the same PoT value later steps will gather
+            # — the chunked admission must reproduce the incremental
+            # write paths bit for bit.
             if kvq:
-                ok = _kv_page_view(ck, ckb, table, span, spec, q.dtype)
-                ov = _kv_page_view(cv, cvb, table, span, spec, q.dtype)
+                ok = _kv_page_view(ck, ckb, table, span, spec, q.dtype, l)
+                ov = _kv_page_view(cv, cvb, table, span, spec, q.dtype, l)
                 kc, kb = compress.kv_page_encode(k, spec)
                 vc, vb = compress.kv_page_encode(v, spec)
                 kf = compress.kv_page_decode(kc, kb, spec).astype(q.dtype)
                 vf = compress.kv_page_decode(vc, vb, spec).astype(q.dtype)
             else:
-                ok = _page_view(ck, table, span) if paged else ck
-                ov = _page_view(cv, table, span) if paged else cv
+                ok = _page_view(ck, table, span, l) if paged else ck[l]
+                ov = _page_view(cv, table, span, l) if paged else cv[l]
                 kf, vf = k, v
+        if paged:
+            nk, nkb = _kv_scatter(ck, ckb, k, dest, loff, spec, l)
+            nv, nvb = _kv_scatter(cv, cvb, v, dest, loff, spec, l)
+        else:
+            nk = ck.at[l, rows[:, None], sidx].set(k.astype(ck.dtype),
+                                                   mode="drop")
+            nv = cv.at[l, rows[:, None], sidx].set(v.astype(cv.dtype),
+                                                   mode="drop")
+        if windowed:
             k_all = jnp.concatenate([ok.astype(q.dtype), kf], axis=1)
             v_all = jnp.concatenate([ov.astype(q.dtype), vf], axis=1)
             kpos_all = jnp.concatenate([kpos_old, qpos], axis=1)
@@ -763,12 +825,12 @@ def chunk_step(cfg, policy, params, tokens, n_new, cache):
             # identical reduction decode_step performs (decode fast-path
             # bit-equality); no window => no ring wrap => safe
             if kvq:
-                kv_k = _kv_page_view(nk, nkb, table, span, spec, q.dtype)
-                kv_v = _kv_page_view(nv, nvb, table, span, spec, q.dtype)
+                kv_k = _kv_page_view(nk, nkb, table, span, spec, q.dtype, l)
+                kv_v = _kv_page_view(nv, nvb, table, span, spec, q.dtype, l)
             else:
-                kv_k = (_page_view(nk, table, span) if paged else nk
+                kv_k = (_page_view(nk, table, span, l) if paged else nk[l]
                         ).astype(q.dtype)
-                kv_v = (_page_view(nv, table, span) if paged else nv
+                kv_v = (_page_view(nv, table, span, l) if paged else nv[l]
                         ).astype(q.dtype)
             att = _sdpa(
                 cfg, policy, q, kv_k, kv_v, qpos, kpos_view, None,
@@ -790,27 +852,16 @@ def chunk_step(cfg, policy, params, tokens, n_new, cache):
             y = y + _moe_apply(cfg, policy, lp["moe"], h2, per_slot=True)
         else:
             y = y + _mlp_apply(cfg, policy, lp["mlp"], h2)
-        out = (nk, nv) + ((nkb, nvb) if kvq else ())
-        return y, out
+        return y, _kv_stores(nk, nv, nkb, nvb)
 
-    xs = (params["layers"], cache["k"], cache["v"])
-    if kvq:
-        xs = xs + (cache["k_beta"], cache["v_beta"])
-    x, scanned = jax.lax.scan(carry_block, x, xs)
+    x, kv = _scan_layers(params, cache, x, layer)
     # emit at each slot's last valid position (gather BEFORE the head so
     # its activation-scale group is the (1, D) row, same as decode_step)
     emit = jnp.clip(n_new - 1, 0, c - 1)
     xe = x[rows, emit][:, None, :]  # (B, 1, D)
     xe = common.apply_norm(cfg.norm, xe, params["final_norm"])
     logits = _lm_head(cfg, policy, params, xe)[:, 0, :]
-    new_cache = {
-        "k": scanned[0],
-        "v": scanned[1],
-        "pos": kpos_new,
-        "len": pos0 + n_new,
-    }
-    if kvq:
-        new_cache["k_beta"], new_cache["v_beta"] = scanned[2], scanned[3]
+    new_cache = dict(kv, pos=kpos_new, len=pos0 + n_new)
     if paged:
         new_cache["table"] = table
     return logits, new_cache
@@ -902,9 +953,8 @@ def verify_step(cfg, policy, params, tokens, n_new, cache):
             )
             kpos_views.append(kpos_phys)
 
-    def carry_block(carry, lp_kv):
-        lp, ck, cv, *betas = lp_kv
-        ckb, cvb = betas if kvq else (None, None)
+    def layer(carry, lp, l, kv):
+        ck, cv, ckb, cvb = (kv.get(n) for n in _KV_STORES)
         outs = []
         for i in range(c):
             xi = carry[:, i:i + 1, :]  # (B, 1, D) — decode's input shape
@@ -923,19 +973,21 @@ def verify_step(cfg, policy, params, tokens, n_new, cache):
             k = common.rope(k, pq, cfg.rope_theta)
             if paged:
                 ck, ckb = _kv_scatter(ck, ckb, k[:, 0], dests[i], loffs[i],
-                                      spec)
+                                      spec, l)
                 cv, cvb = _kv_scatter(cv, cvb, v[:, 0], dests[i], loffs[i],
-                                      spec)
-                kview = _kv_page_view(ck, ckb, table, span, spec, q.dtype)
-                vview = _kv_page_view(cv, cvb, table, span, spec, q.dtype)
+                                      spec, l)
+                kview = _kv_page_view(ck, ckb, table, span, spec, q.dtype,
+                                      l)
+                vview = _kv_page_view(cv, cvb, table, span, spec, q.dtype,
+                                      l)
             else:
-                ck = ck.at[rows, sidxs[i]].set(
+                ck = ck.at[l, rows, sidxs[i]].set(
                     k[:, 0].astype(ck.dtype), mode="drop"
                 )
-                cv = cv.at[rows, sidxs[i]].set(
+                cv = cv.at[l, rows, sidxs[i]].set(
                     v[:, 0].astype(cv.dtype), mode="drop"
                 )
-                kview, vview = ck.astype(q.dtype), cv.astype(q.dtype)
+                kview, vview = ck[l].astype(q.dtype), cv[l].astype(q.dtype)
             att = _sdpa(cfg, policy, q, kview, vview, pq, kpos_views[i],
                         cfg.window)
             att = att.reshape(b, 1, cfg.n_heads * cfg.head_dim)
@@ -948,13 +1000,9 @@ def verify_step(cfg, policy, params, tokens, n_new, cache):
             else:
                 y = y + _mlp_apply(cfg, policy, lp["mlp"], h2)
             outs.append(y)
-        out = (ck, cv) + ((ckb, cvb) if kvq else ())
-        return jnp.concatenate(outs, axis=1), out
+        return jnp.concatenate(outs, axis=1), _kv_stores(ck, cv, ckb, cvb)
 
-    xs = (params["layers"], cache["k"], cache["v"])
-    if kvq:
-        xs = xs + (cache["k_beta"], cache["v_beta"])
-    x, scanned = jax.lax.scan(carry_block, x, xs)
+    x, kv = _scan_layers(params, cache, x, layer)
     # per-position head: each (B, 1, D) slice keeps decode's (1, D)
     # activation-scale group through the final norm and LM head
     logits = []
@@ -963,14 +1011,7 @@ def verify_step(cfg, policy, params, tokens, n_new, cache):
                                params["final_norm"])
         logits.append(_lm_head(cfg, policy, params, xe)[:, 0, :])
     logits = jnp.stack(logits, axis=1)  # (B, C, V)
-    new_cache = {
-        "k": scanned[0],
-        "v": scanned[1],
-        "pos": kpos_phys,
-        "len": pos0 + n_new,
-    }
-    if kvq:
-        new_cache["k_beta"], new_cache["v_beta"] = scanned[2], scanned[3]
+    new_cache = dict(kv, pos=kpos_phys, len=pos0 + n_new)
     if paged:
         new_cache["table"] = table
     return logits, new_cache

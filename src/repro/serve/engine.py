@@ -242,21 +242,24 @@ def _spec_restore_fn():
     return restore_step
 
 
-def _shared_step(kind: str, cfg, policy, body):
+def _shared_step(kind: str, cfg, policy, body, donate=()):
     """Cache-or-build a plan-less jitted step, enforcing at call time that
     the ambient actshard plan matches the one active at build time (it
     would otherwise silently bake into — or be missing from — the shared
-    trace)."""
+    trace).  ``donate`` names the cache argument of a step that returns
+    the updated cache: donated, the step updates the K/V stores in place
+    (``transformer._scan_layers``) instead of writing a second cache, so
+    a caller must use the returned cache and never the one it passed."""
     ambient = actshard.active_plan()
     if ambient is not None:
         # private closure: the ambient plan's constraints bake in at trace
         # time, so this trace must never be shared (plans are unhashable,
         # and id()-keying would risk stale reuse after gc)
-        jitted = jax.jit(body)
+        jitted = jax.jit(body, donate_argnums=donate)
     else:
         key = (kind, cfg, policy)
         if key not in _STEP_CACHE:
-            _STEP_CACHE[key] = jax.jit(body)
+            _STEP_CACHE[key] = jax.jit(body, donate_argnums=donate)
         jitted = _STEP_CACHE[key]
 
     def checked(*args, _jitted=jitted, _ambient=ambient):
@@ -274,14 +277,15 @@ def _shared_step(kind: str, cfg, policy, body):
 def make_prefill_step(cfg: ModelConfig, policy: QuantPolicy,
                       plan: Optional[ShardingPlan] = None):
     """The batched prefill step (``registry.prefill``): consumes a batch
-    dict, returns (last-position logits, filled cache).  Plan-less calls
-    share one jitted closure per (cfg, policy) under the ambient-plan
-    contract of ``_shared_step``; with a ``plan`` the step is jitted
-    against the plan's param/data/cache shardings (cache donated), so the
-    compiled step IS the sharded program — no per-call re-derivation."""
+    dict, returns (last-position logits, filled cache); the cache passed
+    in is donated either way.  Plan-less calls share one jitted closure
+    per (cfg, policy) under the ambient-plan contract of
+    ``_shared_step``; with a ``plan`` the step is jitted against the
+    plan's param/data/cache shardings, so the compiled step IS the
+    sharded program — no per-call re-derivation."""
     prefill_step = _prefill_fn(cfg, policy)
     if plan is None:
-        return _shared_step("prefill", cfg, policy, prefill_step)
+        return _shared_step("prefill", cfg, policy, prefill_step, (2,))
     b = _plan_batch(plan)
     cache_sh = plan.cache_shardings()
     return jax.jit(
@@ -307,7 +311,7 @@ def make_decode_step(cfg: ModelConfig, policy: QuantPolicy, *,
     literally the same compiled step."""
     decode_step = _decode_fn(cfg, policy)
     if plan is None:
-        return _shared_step("decode", cfg, policy, decode_step)
+        return _shared_step("decode", cfg, policy, decode_step, (2,))
     b = _plan_batch(plan)
     cache_sh = plan.cache_shardings()
     tok_sh = plan.named(plan.token_pspec(b))
@@ -336,7 +340,7 @@ def make_chunk_step(cfg: ModelConfig, policy: QuantPolicy, *,
     width), so the closure is shared exactly like the decode step's."""
     chunk_step = _chunk_fn(cfg, policy)
     if plan is None:
-        return _shared_step("chunk", cfg, policy, chunk_step)
+        return _shared_step("chunk", cfg, policy, chunk_step, (3,))
     b = _plan_batch(plan)
     cache_sh = plan.cache_shardings()
     tok_sh = plan.named(plan.token_pspec(b))
@@ -368,7 +372,7 @@ def make_verify_step(cfg: ModelConfig, policy: QuantPolicy, *,
     is shared exactly like the chunk step's."""
     verify_step = _verify_fn(cfg, policy)
     if plan is None:
-        return _shared_step("verify", cfg, policy, verify_step)
+        return _shared_step("verify", cfg, policy, verify_step, (3,))
     b = _plan_batch(plan)
     cache_sh = plan.cache_shardings()
     chunk_sh = plan.named(plan.chunk_pspec(b))
@@ -727,7 +731,7 @@ class PoolEngine:
             f"spec_snap{c}", self.cfg, self.policy, _spec_snap_fn(c)
         )
         self._spec_restore = _shared_step(
-            "spec_restore", self.cfg, self.policy, _spec_restore_fn()
+            "spec_restore", self.cfg, self.policy, _spec_restore_fn(), (0,)
         )
         if spec.needs_draft_pass:
             # same weights, 2-3 PoT bits, re-quantized at use; the
@@ -736,7 +740,7 @@ class PoolEngine:
             dpol = draft_policy(self.policy, spec.bits)
             self._draft = _shared_step(
                 f"spec_draft{spec.max_draft}", self.cfg, dpol,
-                _draft_fn(self.cfg, dpol, spec.max_draft),
+                _draft_fn(self.cfg, dpol, spec.max_draft), (2,),
             )
 
     # -- request admission -------------------------------------------------
