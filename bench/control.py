@@ -36,7 +36,7 @@ TRAIN_VARIANTS = ("program", "control", "half_batch", "no_dgamma")
 def train_readings(run, variants=TRAIN_VARIANTS) -> dict:
     import jax
 
-    from bench.lib import check, reference, train_cell
+    from bench.lib import check, refmath, train_cell
 
     tr, prog = train_cell.checked_steps(run)
     del tr
@@ -44,11 +44,11 @@ def train_readings(run, variants=TRAIN_VARIANTS) -> dict:
     ref = train_cell.reference_readings(run)
     make = {
         "control": lambda: train_cell.reference_readings(
-            run, reference.Num(True)),
+            run, refmath.Num(True)),
         "half_batch": lambda: train_cell.reference_readings(
             run, rows=run.traffic["batch"] // 2),
         "no_dgamma": lambda: train_cell.reference_readings(
-            run, reference.Num(no_dgamma=True)),
+            run, refmath.Num(no_dgamma=True)),
     }
     runs = [("program", prog)] + [(v, make[v]()) for v in variants
                                   if v != "program"]
@@ -70,7 +70,7 @@ def serve_readings(run) -> dict:
     import jax
     import numpy as np
 
-    from bench.lib import reference, serve_cell, traffic as traffic_lib
+    from bench.lib import refmath, serve_cell, traffic as traffic_lib
 
     cell, cfg_json = run.cell, run.cfg
     eng_cfg = cell["engine"]
@@ -90,8 +90,8 @@ def serve_readings(run) -> dict:
     served = [(r, np.asarray(out[r["uid"]])) for r in reqs]
     del eng, params
     jax.clear_caches()
-    res = serve_cell.compare(run, served, nums=(reference.Num(False),
-                                                reference.Num(True)))
+    res = serve_cell.compare(run, served, nums=(refmath.Num(False),
+                                                refmath.Num(True)))
     return {"program": res["detail"]["readings"],
             "control": res["detail"]["control"],
             "altered_token": res["detail"]["altered_token"],

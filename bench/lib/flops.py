@@ -10,11 +10,9 @@
   So no implementation can read above its roofline, one that streams int8
   or nibble codes included.  (The arithmetic of ``benchmarks/kernelbench.py``
   counted operations and intensity the same way, at 2 bytes an operand.)
-* ``model_flops_*``: the useful FLOPs of the model, as in
-  ``benchmarks/roofline.py`` (2 per weight per token forward, 6 with the
-  backward), written out per matmul so the LM head counts the real
-  vocabulary and causal attention its real context.  Recomputation is not
-  counted.
+
+A model's useful FLOPs depend on its architecture and are its model
+module's (``bench/lib/model.py``).
 """
 from __future__ import annotations
 
@@ -26,51 +24,6 @@ def matmul_cost(m: int, k: int, n: int, bits_a: float, bits_b: float,
     ops = 2.0 * m * k * n
     nbytes = m * k * bits_a / 8 + k * n * bits_b / 8 + m * n * out_bytes
     return ops, nbytes
-
-
-def linear_shapes(cfg: dict):
-    """(name, K, N) of each linear of one layer."""
-    d, hd, f = cfg["d_model"], cfg["head_dim"], cfg["d_ff"]
-    h, kv = cfg["n_heads"], cfg["kv_heads"]
-    out = [("wq", d, h * hd), ("wk", d, kv * hd), ("wv", d, kv * hd),
-           ("wo", h * hd, d)]
-    if cfg["act"] == "swiglu":
-        out += [("wi_gate", d, f), ("wi_up", d, f)]
-    else:
-        out += [("wi", d, f)]
-    return out + [("mlp_wo", f, d)]
-
-
-def layer_flops_per_row(cfg: dict) -> float:
-    return sum(2.0 * k * n for _, k, n in linear_shapes(cfg))
-
-
-def attn_flops(cfg: dict, context: float) -> float:
-    """QK^T and PV of one query row against ``context`` keys."""
-    return 4.0 * cfg["n_heads"] * cfg["head_dim"] * context
-
-
-def head_flops_per_row(cfg: dict) -> float:
-    return 2.0 * cfg["d_model"] * cfg["vocab"]
-
-
-def model_flops_train_per_token(cfg: dict, seq: int) -> float:
-    """Forward plus backward (3x forward) per token of a causal sequence."""
-    fwd = (cfg["n_layers"] * (layer_flops_per_row(cfg)
-                              + attn_flops(cfg, (seq + 1) / 2))
-           + head_flops_per_row(cfg))
-    return 3.0 * fwd
-
-
-def model_flops_serve_request(cfg: dict, prompt: int, served: int) -> float:
-    """Useful forward FLOPs of one request: every prompt row and every fed
-    token (the last served token is never fed) through the layers, each
-    against its causal context, and the LM head once per served token."""
-    rows = prompt + served - 1
-    total_ctx = rows * (rows + 1) / 2  # sum of (position + 1)
-    return (cfg["n_layers"] * (rows * layer_flops_per_row(cfg)
-                               + attn_flops(cfg, 1.0) * total_ctx)
-            + served * head_flops_per_row(cfg))
 
 
 def kernel_call_cost(kind: str, m: int, k: int, n: int,

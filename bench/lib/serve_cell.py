@@ -22,7 +22,8 @@ import time
 
 import numpy as np
 
-from bench.lib import check, reference, traffic as traffic_lib
+from bench.lib import check, model as model_lib, refmath
+from bench.lib import traffic as traffic_lib
 from bench.lib import weights as W
 from bench.lib.seeds import rng, seed_arg
 from bench.lib.train_cell import flat, model_config
@@ -42,23 +43,23 @@ def make_weights(seed: int, cfg_json: dict, cfg, policy):
 
     from repro.serve.quantized_weights import quantize_for_serving
 
+    model = model_lib.load(cfg_json)
     out = {}
-    for name, (_, _, per_layer) in W.layout(cfg_json).items():
+    for name, (_, _, depth) in model.layout(cfg_json).items():
         if name.endswith("/w"):
             def quant(s, l, name=name):
                 x = W.leaf(s, cfg_json, name, l)
                 return quantize_for_serving(cfg, policy, {"w": x})["w"]
 
-            if per_layer:
-                fn = jax.jit(lambda s, quant=quant: jax.lax.map(
-                    functools.partial(quant, s),
-                    jax.numpy.arange(cfg_json["n_layers"])))
+            if depth is not None:
+                fn = jax.jit(lambda s, quant=quant, depth=depth: jax.lax.map(
+                    functools.partial(quant, s), jax.numpy.arange(depth)))
             else:
                 fn = jax.jit(lambda s, quant=quant: quant(s, 0))
         else:
             fn = jax.jit(lambda s, name=name: W.leaf(s, cfg_json, name))
         out[name] = fn(seed_arg(seed))
-    return W.empty_norms(cfg_json, W.nest(out))
+    return model.tree(cfg_json, out)
 
 
 def to_requests(call):
@@ -81,6 +82,7 @@ def run_cell(run):
     eng_cfg = cell["engine"]
     chunk = eng_cfg["prefill_chunk"]
     with TraceAnnotation("bench.setup"):
+        model_lib.load(cfg_json, need=model_lib.SERVE)
         cfg = model_config(cfg_json)
         policy = serve_policy(cell)
         want = {k: v.shape for k, v in flat(pspec.abstract(
@@ -155,7 +157,7 @@ def sample(seed: int, served, min_tokens: int):
     return [served[i] for i in picked]
 
 
-def compare(run, served, nums=(reference.Num(False),)):
+def compare(run, served, nums=(refmath.Num(False),)):
     """The served tokens' readings under the reference.  With a second
     ``num`` (the control) also the readings of that control's own first
     choices (``control``) and of every served token altered by one
@@ -164,9 +166,9 @@ def compare(run, served, nums=(reference.Num(False),)):
     picked = sample(run.seed, served, cell["check_tokens"])
     seqs = [(np.concatenate([r["tokens"].reshape(-1), toks]),
              r["tokens"].shape[-1]) for r, toks in picked]
-    ref = reference.StepReference(seed_arg(run.seed), run.cfg,
-                                  cell["engine"]["prefill_chunk"],
-                                  cell["engine"]["max_len"])
+    ref = model_lib.load(run.cfg, need=model_lib.SERVE).StepReference(
+        seed_arg(run.seed), run.cfg, cell["engine"]["prefill_chunk"],
+        cell["engine"]["max_len"])
     logits = ref.logits(seqs, list(nums))
     base = logits[nums[0]]
     n_tokens = sum(len(toks) for _, toks in picked)

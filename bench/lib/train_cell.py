@@ -17,7 +17,8 @@ import time
 
 import numpy as np
 
-from bench.lib import check, reference, traffic as traffic_lib
+from bench.lib import check, model as model_lib, refmath
+from bench.lib import traffic as traffic_lib
 from bench.lib import weights as W
 from bench.lib.seeds import seed_arg
 
@@ -58,6 +59,7 @@ def build(run):
     from repro.train import TrainConfig, make_train_step
 
     cell, cfg_json = run.cell, run.cfg
+    model = model_lib.load(cfg_json)
     opt_cfg = cell["optimizer"]
     cfg = model_config(cfg_json)
     policy = dataclasses.replace(policy_lib.PAPER_FAITHFUL,
@@ -68,8 +70,8 @@ def build(run):
     plan = planner.plan_for(cfg, mesh, shape=shape)
     want = {k: v.shape for k, v in flat(pspec.abstract(
         registry.param_specs(cfg))).items()}
-    have = {k: tuple(v[0]) if not v[2] else (cfg.n_layers, *v[0])
-            for k, v in W.layout(cfg_json).items()}
+    have = {k: tuple(shape) if depth is None else (depth, *shape)
+            for k, (shape, _, depth) in model.layout(cfg_json).items()}
     if want != have:
         raise RuntimeError(f"the program's parameters {want} are not the "
                            f"benchmark's {have}")
@@ -83,8 +85,8 @@ def build(run):
     seed = seed_arg(run.seed)
 
     def make_params(s):
-        return W.empty_norms(cfg_json, W.nest(
-            {n: W.leaf(s, cfg_json, n) for n in W.layout(cfg_json)}))
+        return model.tree(cfg_json, {n: W.leaf(s, cfg_json, n)
+                                     for n in model.layout(cfg_json)})
 
     with jax.set_mesh(mesh):
         params = jax.jit(make_params, out_shardings=param_sh)(seed)
@@ -219,11 +221,14 @@ def reference_batches(run, rows=None):
     return out
 
 
-def reference_readings(run, num=reference.Num(False), rows=None):
-    return reference.train_readings(seed_arg(run.seed), run.cfg,
-                                    run.cell["optimizer"],
-                                    reference_batches(run, rows), num,
-                                    CHECKED_STEPS)
+def reference_readings(run, num=refmath.Num(False), rows=None):
+    """The reference's readings of the checked steps, through the loss of
+    the configuration's model module."""
+    return refmath.train_readings(model_lib.load(run.cfg),
+                                  seed_arg(run.seed), run.cfg,
+                                  run.cell["optimizer"],
+                                  reference_batches(run, rows), num,
+                                  CHECKED_STEPS)
 
 
 def compare(run, readings):

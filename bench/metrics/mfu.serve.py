@@ -5,7 +5,7 @@ not counted), over the window's seconds and the chip's bf16 peak, in %."""
 
 
 def read(ctx):
-    rec, f = ctx["record"], ctx["flops"]
-    total = sum(f.model_flops_serve_request(ctx["cfg"], p, s)
+    rec, model = ctx["record"], ctx["model"]
+    total = sum(model.model_flops_serve_request(ctx["cfg"], p, s)
                 for p, s in rec["requests"])
     return 100.0 * total / rec["window_s"] / ctx["peak"]["bf16_flops"]

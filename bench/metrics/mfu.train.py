@@ -4,7 +4,8 @@ window, over the chip's bf16 peak, in %."""
 
 
 def read(ctx):
-    rec, f = ctx["record"], ctx["flops"]
-    per_token = f.model_flops_train_per_token(ctx["cfg"], ctx["traffic"]["seq"])
+    rec, model = ctx["record"], ctx["model"]
+    per_token = model.model_flops_train_per_token(ctx["cfg"],
+                                                  ctx["traffic"]["seq"])
     return (100.0 * rec["train_tokens_per_s"] * per_token
             / ctx["peak"]["bf16_flops"])

@@ -8,6 +8,8 @@ workload names its configuration (``bench/configs/<config>.json``) and
 its traffic (``bench/traffic/<traffic>.json``); ``bench/cells/<workload>.json``
 holds its kind (train or serve), the program's settings and the limits of
 its check; each per-layer metric is read by ``bench/metrics/<metric>.py``.
+The configuration's ``reference`` key names its model module
+(``bench/lib/model.py``): parameter layout, plain reference, model FLOPs.
 
 With ``--trace 0`` the result carries the cell's end-to-end metrics; with
 ``--trace 1`` the window runs under the profiler and the result carries
@@ -145,13 +147,14 @@ def execute(run: Run) -> dict:
             value = run.setup_s if m["name"] == "setup_s" else rec[m["name"]]
             result["metrics"][m["name"]] = {"value": value, "unit": m["unit"]}
     else:
-        from bench.lib import device, flops, weights
+        from bench.lib import device, flops, model, weights
 
         red = reduce_trace(run)
         result["device"]["busy_s"] = red["busy_s"]
         result["device"]["window_s"] = red["window_s"]
         ctx = {"record": rec, "trace": red, "cell": run.cell, "cfg": run.cfg,
                "traffic": run.traffic, "flops": flops,
+               "model": model.load(run.cfg),
                "vocab_padded": weights.vocab_padded(run.cfg),
                "peak": device.peaks(result["device"]["kind"])}
         for m in run.spec["per_layer"]:

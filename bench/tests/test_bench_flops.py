@@ -1,10 +1,11 @@
-"""Operations, bytes, model FLOPs and the table of peaks."""
+"""Operations, bytes, the dense decoder's model FLOPs and the table of
+peaks."""
 import json
 import os
 
 import pytest
 
-from bench.lib import device, flops
+from bench.lib import device, flops, reference
 
 HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -27,7 +28,8 @@ def test_model_flops_olmo_train_per_token():
     attn = 4 * 16 * 128 * (2048 + 1) / 2
     head = 2 * 2048 * 50304
     want = 3 * (8 * (per_layer + attn) + head)
-    assert flops.model_flops_train_per_token(cfg, 2048) == pytest.approx(want)
+    assert reference.model_flops_train_per_token(cfg, 2048) == \
+        pytest.approx(want)
 
 
 def test_model_flops_serve_request_counts_fed_rows():
@@ -35,12 +37,12 @@ def test_model_flops_serve_request_counts_fed_rows():
     cfg = {"n_layers": 16, "d_model": 4608, "n_heads": 36, "kv_heads": 4,
            "head_dim": 128, "d_ff": 18432, "vocab": 49152, "act": "gelu"}
     rows = 100 + 10 - 1
-    want = (16 * (rows * flops.layer_flops_per_row(cfg)
+    want = (16 * (rows * reference.layer_flops_per_row(cfg)
                   + 4 * 36 * 128 * rows * (rows + 1) / 2)
             + 10 * 2 * 4608 * 49152)
-    assert flops.model_flops_serve_request(cfg, 100, 10) == \
+    assert reference.model_flops_serve_request(cfg, 100, 10) == \
         pytest.approx(want)
-    assert flops.layer_flops_per_row(cfg) == 2 * (
+    assert reference.layer_flops_per_row(cfg) == 2 * (
         4608 * 4608 * 2 + 2 * 4608 * 512 + 2 * 4608 * 18432)
 
 

@@ -1,6 +1,7 @@
 """The plain reference against the program's own pieces, at small sizes
-on the CPU: the PoT quantizer bit for bit, and the MF-MAC loss and its
-gradients through the program's training path."""
+on the CPU: the PoT quantizer bit for bit, and the dense decoder's MF-MAC
+loss and its gradients, through the model module's interface, against the
+program's training path."""
 import dataclasses
 
 import jax
@@ -8,7 +9,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench.lib import reference as R
+from bench.lib import model
+from bench.lib import refmath as R
 from bench.lib import weights as W
 from bench.tests import tiny
 
@@ -50,9 +52,10 @@ def _program_and_reference_grads(num):
     cfg = model_config(cfg_json)
     from bench.lib.seeds import seed_arg
 
+    dense = model.load(cfg_json)
     params = {n: W.leaf(seed_arg(tiny.SEED), cfg_json, n)
-              for n in W.layout(cfg_json)}
-    tree = W.empty_norms(cfg_json, W.nest(params))
+              for n in dense.layout(cfg_json)}
+    tree = dense.tree(cfg_json, params)
     toks = jax.random.randint(jax.random.PRNGKey(2), (2, 16), 0, 257)
     batch = {"tokens": toks, "labels": jnp.roll(toks, -1, 1),
              "mask": jnp.ones((2, 16)).at[:, -1].set(0.0)}
@@ -62,7 +65,7 @@ def _program_and_reference_grads(num):
     pl, pg = jax.value_and_grad(lambda s: registry.loss_fn(
         cfg, dataclasses.replace(pol, weights_prequantized=True), s,
         batch))(shadow)
-    rl, rg = jax.value_and_grad(R.loss_fn, argnums=1)(
+    rl, rg = jax.value_and_grad(dense.loss_fn, argnums=1)(
         cfg_json, params, batch["tokens"], batch["labels"], batch["mask"],
         num)
     return pl, flat(pg), rl, rg

@@ -10,7 +10,8 @@ SEED = 2**31 + 12345
 def cfg(gqa: bool = False) -> dict:
     """A smoke-size decoder.  Attention's operands are stated float32: the
     program's float32 dots run at full precision on the CPU."""
-    return {"name": "tiny", "family": "decoder", "n_layers": 2,
+    return {"name": "tiny", "reference": "bench/lib/reference.py",
+            "family": "decoder", "n_layers": 2,
             "d_model": 64, "n_heads": 4, "kv_heads": 2 if gqa else 4,
             "head_dim": 16, "d_ff": 128, "vocab": 257,
             "vocab_pad_multiple": 64, "act": "gelu" if gqa else "swiglu",
